@@ -3,9 +3,9 @@
 Perf rows are only comparable across PRs if the process environment that
 produced them is pinned — the olmax run.sh idiom (SNIPPETS.md): force the
 host platform device count so XLA's thread pools are carved identically on
-every run, place the step marker at the outer loop, silence the TF log spam
-that skews short timings, and record whether tcmalloc is preloaded (the
-single biggest allocator effect on numpy-heavy benches).
+every run, silence the TF log spam that skews short timings, and record
+whether tcmalloc is preloaded (the single biggest allocator effect on
+numpy-heavy benches) and whether the host carries TPU chips.
 
 Usage, at the very top of a bench module (before anything imports jax)::
 
@@ -26,29 +26,37 @@ import os
 import platform
 import sys
 
-#: XLA flag pinned on TPU hosts only (merged into any caller-set flags).
-#: 0 = program entry, 1 = outermost while loop — the olmax placement.  The
-#: CPU build of XLA does not compile this flag in and hard-aborts on it at
-#: import (measured), so it is applied exactly when TPU hardware is present;
-#: the fingerprint records which way it went.
-STEP_MARKER_FLAG = "--xla_step_marker_location=1"
-
-#: Where TPU accelerators appear on a TPU VM.  Module-level so tests can
-#: point it at a tmp path and exercise the TPU leg without hardware.
+#: How a TPU host shows its chips before JAX is imported.  A v5e host has no
+#: /dev/accel* nodes: its chips are PCI functions (Google's vendor id 0x1ae0,
+#: device 0x0063) bound to /dev/vfio/<n>, measured on a v5litepod-4 host.
+#: Older TPU VMs expose /dev/accel*.  Module-level so tests can point the
+#: globs at a tmp path and exercise the TPU leg without hardware.
 ACCEL_DEVICE_GLOB = "/dev/accel*"
+PCI_DEVICE_GLOB = "/sys/bus/pci/devices/*"
+TPU_PCI_IDS = {("0x1ae0", "0x0063")}  # (vendor, device): TPU v5e
 
 _state: dict = {
     "applied": False,
     "late": False,
     "host_devices": None,
-    "step_marker": False,
+    "tpu_host": False,
 }
 
 
+def _pci_id(dev: str) -> tuple[str, str] | None:
+    try:
+        with open(os.path.join(dev, "vendor")) as v, open(os.path.join(dev, "device")) as d:
+            return v.read().strip(), d.read().strip()
+    except OSError:
+        return None
+
+
 def _tpu_hardware_present() -> bool:
-    """A TPU VM exposes its accelerators as /dev/accel* (libtpu merely being
-    pip-installed — as in this CPU container — does not count)."""
-    return bool(glob.glob(ACCEL_DEVICE_GLOB))
+    """True on a host that carries TPU chips (libtpu merely being
+    pip-installed, as on a CPU-only machine, does not count)."""
+    if glob.glob(ACCEL_DEVICE_GLOB):
+        return True
+    return any(_pci_id(d) in TPU_PCI_IDS for d in glob.glob(PCI_DEVICE_GLOB))
 
 
 def apply(host_devices: int = 1) -> dict:
@@ -57,10 +65,11 @@ def apply(host_devices: int = 1) -> dict:
     rather than silently measuring an unpinned process."""
     _state["late"] = "jax" in sys.modules
     _state["host_devices"] = host_devices
+    # No TPU-only flag is pinned: the runtime on a v5e aborts on an XLA flag
+    # it does not know (--xla_step_marker_location, in XLA_FLAGS and in
+    # LIBTPU_INIT_ARGS alike), so the TPU leg only records the hardware.
     flags = [f"--xla_force_host_platform_device_count={host_devices}"]
-    _state["step_marker"] = _tpu_hardware_present()
-    if _state["step_marker"]:
-        flags.append(STEP_MARKER_FLAG)
+    _state["tpu_host"] = _tpu_hardware_present()
     existing = os.environ.get("XLA_FLAGS", "")
     merged = existing.split() if existing else []
     for f in flags:
@@ -94,7 +103,7 @@ def fingerprint() -> dict:
         "applied": _state["applied"],
         "late": _state["late"],
         "host_devices": _state["host_devices"],
-        "step_marker": _state["step_marker"],
+        "tpu_host": _state["tpu_host"],
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "tcmalloc": tcmalloc_loaded(),
         "backend": jax.default_backend(),

@@ -41,7 +41,7 @@ from benchmarks.common import (
     row,
     write_json,
 )
-from repro.core.precision_policy import PrecisionPolicy
+from repro.core.precision_policy import Precision, PrecisionPolicy
 from repro.core.pruning import plan_prune
 from repro.data import features
 from repro.models import cnn1d
@@ -331,69 +331,6 @@ def bench_bursty(n_streams: int, params, cfg) -> dict:
     }
 
 
-# The front-end comparison runs in a subprocess on the DEFAULT single-device
-# environment: this process's 8-simulated-device pool (needed only for the
-# shard rows) splits the XLA CPU thread pool eight ways, which starves the
-# fused in-graph FFTs while leaving the single-threaded numpy loop almost
-# untouched — a simulation artifact that would understate the on-device win.
-# Each emitted row records host_devices=1 accordingly.
-FRONTEND_SCRIPT = """\
-import os, json, sys, time
-# The parent process baked --xla_force_host_platform_device_count=8 into
-# XLA_FLAGS (inherited via os.environ); strip that flag — and only it, an
-# outer override of anything else still wins — so this child really runs
-# on the default single-device pool.
-os.environ["XLA_FLAGS"] = " ".join(
-    f for f in os.environ.get("XLA_FLAGS", "").split()
-    if not f.startswith("--xla_force_host_platform_device_count")
-)
-import numpy as np
-sys.path.insert(0, "src")
-import jax
-from repro.core.precision_policy import Precision, PrecisionPolicy
-from repro.data import features
-from repro.models import cnn1d
-from repro.serving.engine import MonitorEngine
-
-counts = [int(c) for c in sys.argv[1:]]
-wps = int(%d)
-cfg = cnn1d.CNNConfig(
-    input_len=features.FEATURE_DIMS["%s"], channels=(4, 8), hidden=8
-)
-params = cnn1d.init_params(jax.random.PRNGKey(2), cfg)
-policy = PrecisionPolicy(rules={}, default=Precision.FP32)
-out = []
-for on_device in (False, True):
-    for n in counts:
-        rng = np.random.default_rng(n)
-        engine = MonitorEngine(
-            params, cfg, n_streams=n, feature_kind="%s",
-            on_device_features=on_device, batch_slots=%d, policy=policy,
-        )
-        audio = rng.standard_normal((n, wps * features.N_SAMPLES)).astype(np.float32)
-        engine.push(0, audio[0, : features.N_SAMPLES])
-        engine.drain()  # compile outside the timed region
-        # the warmup dispatch must not leak into the reported dispatch stats
-        engine.forward_calls = 0
-        engine.padded_slots = 0
-        t0 = time.perf_counter()
-        for s in range(n):
-            off = features.N_SAMPLES if s == 0 else 0
-            engine.push(s, audio[s, off:])
-        scored = engine.drain()
-        dt = time.perf_counter() - t0
-        out.append({
-            "on_device": on_device, "n_streams": n, "windows": len(scored),
-            "windows_per_s": len(scored) / dt,
-            "us_per_window": dt / len(scored) * 1e6,
-            "forward_calls": engine.forward_calls,
-            "padded_slots": engine.padded_slots,
-            "host_devices": jax.device_count(),
-        })
-print("RESULT:" + json.dumps(out))
-"""
-
-
 def bench_frontend_rows():
     """Host numpy features vs the fused on-device front-end on the paper-
     canonical mfcc20 set at equal stream counts (acceptance: on-device >= 3x
@@ -404,22 +341,26 @@ def bench_frontend_rows():
     front-end difference entirely — on real hardware the classifier is
     microseconds and the pipeline is front-end-bound, which is exactly the
     regime the fp32-policy CNN reproduces on CPU.
-    """
-    import subprocess
-    import sys
 
+    Runs in this process, so one process holds the chip.  On the CPU the
+    8-device pool splits XLA's thread pool, which starves the in-graph FFTs
+    and understates the on-device leg; each row records ``host_devices``.
+    """
     counts = FRONTEND_STREAMS[:1] if _smoke() else FRONTEND_STREAMS
-    script = FRONTEND_SCRIPT % (
-        WINDOWS_PER_STREAM, FRONTEND_FEATURE, FRONTEND_FEATURE, BATCH_SLOTS
+    cfg = cnn1d.CNNConfig(
+        input_len=features.FEATURE_DIMS[FRONTEND_FEATURE], channels=(4, 8), hidden=8
     )
-    proc = subprocess.run(
-        [sys.executable, "-c", script, *map(str, counts)],
-        capture_output=True, text=True, timeout=900,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"front-end bench subprocess failed:\n{proc.stderr[-2000:]}")
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    results = json.loads(line[len("RESULT:"):])
+    params = cnn1d.init_params(jax.random.PRNGKey(2), cfg)
+    policy = PrecisionPolicy(rules={}, default=Precision.FP32)
+    results = [
+        dict(
+            bench_monitor(n, params, cfg, feature=FRONTEND_FEATURE,
+                          policy=policy, on_device_features=on_device),
+            n_streams=n, on_device=on_device,
+        )
+        for on_device in (False, True)
+        for n in counts
+    ]
     host_rate = {
         r["n_streams"]: r["windows_per_s"] for r in results if not r["on_device"]
     }
@@ -439,14 +380,13 @@ def bench_frontend_rows():
             f"front-end-bound regime — interpret-mode int8 kernels would "
             f"mask the front-end); {r['windows_per_s']:.1f} windows/s "
             f"aggregate; {r['forward_calls']} forward calls "
-            f"({BATCH_SLOTS} slots, {r['padded_slots']} padded); subprocess "
-            f"on the default device pool (see FRONTEND_SCRIPT note)",
+            f"({BATCH_SLOTS} slots, {r['padded_slots']} padded)",
             windows_per_s=round(r["windows_per_s"], 2),
             n_streams=r["n_streams"],
             batch_slots=BATCH_SLOTS,
             feature=FRONTEND_FEATURE,
             on_device_features=r["on_device"],
-            host_devices=r["host_devices"],
+            host_devices=jax.device_count(),
         )
 
 

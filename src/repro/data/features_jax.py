@@ -19,13 +19,15 @@ Two numerical contracts, deliberately different in strength:
   Every op in the pipeline is either batched with strictly per-row
   arithmetic — framing/gather, windowing, FFT (each 1-D transform is an
   independent computation; no cross-transform arithmetic exists),
-  elementwise math, and reductions over per-row axes — or, for the two
-  projections where that does NOT hold (mel filterbank and DCT-II: XLA gemm
-  blocking reassociates the contraction as the M dimension grows, which is
-  measurably batch-shape-dependent on CPU, and ``vmap``-ed batched gemm
-  re-blocks the same way), run under ``jax.lax.map`` so each row gets the
-  identical fixed-shape matmul regardless of batch size, slot position, or
-  co-batch content.  The streaming == batched == sharded conformance
+  elementwise math, and order-free reductions (max, counts) — or is pinned
+  where the compiler would choose an association that follows the batch
+  shape: the two projections (mel filterbank and DCT-II: XLA gemm blocking
+  reassociates the contraction as the M dimension grows, measurably on CPU,
+  and ``vmap``-ed batched gemm re-blocks the same way) run under
+  ``jax.lax.map`` so each row gets the identical fixed-shape matmul, and
+  every float mean is a fixed pairwise tree of slice adds
+  (:func:`_pairwise_mean`; on a TPU v5e a reduce op's association followed
+  the batch shape).  The streaming == batched == sharded conformance
   guarantee needs feature bits that survive re-batching and shard-local
   recomputation; tests/test_features_jax.py pins the property across batch
   sizes, permutations and silence padding.
@@ -90,17 +92,38 @@ def _dct32(n_out: int, n_in: int) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _pairwise_mean(v: jax.Array, axis: int = -1) -> jax.Array:
+    """Mean over ``axis``, summed as a fixed tree of slice adds.
+
+    A reduce op's association is the compiler's to choose, and on the TPU it
+    follows the layout XLA picks for the whole batched array: on a v5e the
+    Welch, pooled-mel, PSD-band, ZCR-statistic and normalisation means of one
+    row rounded differently in batches of 1, 16 and 128.  Elementwise adds of
+    fixed slices have one association on every backend and at every batch
+    size, and cost about the same there.
+    """
+    v = jnp.moveaxis(v, axis, -1)
+    n = v.shape[-1]
+    while v.shape[-1] > 1:
+        h = v.shape[-1] // 2
+        v = jnp.concatenate([v[..., :h] + v[..., h : 2 * h], v[..., 2 * h :]], axis=-1)
+    return v[..., 0] / n
+
+
 def _project_rows(x: jax.Array, m: np.ndarray) -> jax.Array:
     """(B, F, K) @ (K, M) -> (B, F, M) with per-row-bitwise guarantees.
 
-    The one place the batched formulation would leak across rows: XLA lowers
+    Where the batched formulation would leak across rows: XLA lowers
     both ``reshape+matmul`` and a ``vmap``-ed matmul to gemms whose blocking
     (and therefore contraction association) changes with the batched M
     dimension.  ``lax.map`` pins each row to the identical (F, K) @ (K, M)
     gemm instead; the projections are small (<2 MFLOP/row), so the scan cost
-    is noise next to the batched FFTs.
+    is noise next to the batched FFTs.  HIGHEST holds the fp32 contract on
+    the TPU, whose default matmul precision is one bf16 pass.
     """
-    return jax.lax.map(lambda q: q @ m, x)
+    return jax.lax.map(
+        lambda q: jnp.matmul(q, m, precision=jax.lax.Precision.HIGHEST), x
+    )
 
 
 def _stft_power(x: jax.Array, n_fft: int = N_FFT, hop: int = HOP) -> jax.Array:
@@ -131,7 +154,7 @@ def _welch_psd(x: jax.Array, n_bins: int = 512) -> jax.Array:
     n_seg = x.shape[1] // seg
     segs = x[:, : n_seg * seg].reshape(-1, n_seg, seg) * _hann32(seg)
     spec = jnp.fft.rfft(segs, axis=-1)
-    p = jnp.mean(spec.real**2 + spec.imag**2, axis=1)[:, :n_bins]
+    p = _pairwise_mean(spec.real**2 + spec.imag**2, axis=1)[:, :n_bins]
     return jnp.log10(p + 1e-10)
 
 
@@ -145,8 +168,8 @@ def _zcr(x: jax.Array, n_frames: int = 128) -> jax.Array:
 
 def _normalize(v: jax.Array) -> jax.Array:
     """Zero-mean, unit-RMS (paper §IV-A), per row."""
-    v = v - jnp.mean(v, axis=1, keepdims=True)
-    rms = jnp.sqrt(jnp.mean(v**2, axis=1, keepdims=True))
+    v = v - _pairwise_mean(v)[:, None]
+    rms = jnp.sqrt(_pairwise_mean(v**2))[:, None]
     return v / (rms + 1e-8)
 
 
@@ -160,15 +183,17 @@ def _feature_batch(x: jax.Array, kind: str) -> jax.Array:
     x = x / peak
     if kind == "mfcc20":
         m = _mfcc(x, 20)[:, :51].reshape(bsz, -1)
-        pooled = _melspectrogram(x, 64).mean(axis=1)
+        pooled = _pairwise_mean(_melspectrogram(x, 64), axis=1)
         p = _welch_psd(x, 512)
-        p10 = p[:, :510].reshape(bsz, 10, 51).mean(axis=2)
+        p10 = _pairwise_mean(p[:, :510].reshape(bsz, 10, 51))
         z = _zcr(x)
-        aux = jnp.stack([z.mean(axis=1), z.std(axis=1)], axis=1)
+        zm = _pairwise_mean(z)
+        zs = jnp.sqrt(_pairwise_mean((z - zm[:, None]) ** 2))
+        aux = jnp.stack([zm, zs], axis=1)
         v = jnp.concatenate([m, pooled, p10, aux], axis=1)
     elif kind == "mel128":
         logmel = _melspectrogram(x, 128)[:, :48]
-        v = logmel.reshape(bsz, 8, 6, 128).mean(axis=2).reshape(bsz, -1)
+        v = _pairwise_mean(logmel.reshape(bsz, 8, 6, 128), axis=2).reshape(bsz, -1)
     elif kind == "psd":
         v = _welch_psd(x, 512)
     elif kind == "zcr":

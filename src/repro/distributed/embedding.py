@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import active_rules
@@ -60,11 +59,11 @@ def embedding_gather(table: jax.Array, ids: jax.Array) -> jax.Array:
         return out.reshape(ids_l.shape + (tbl.shape[1],))
 
     flat_ids = ids.reshape(ids.shape[0], -1)
-    out = shard_map(
+    out = jax.shard_map(
         local_gather,
         mesh=mesh,
         in_specs=(table_spec, P(ids_spec[0] if len(ids_spec) else None, None)),
         out_specs=P(out_spec[0], None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, flat_ids)
     return out.reshape(ids.shape + (table.shape[1],))

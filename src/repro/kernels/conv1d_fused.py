@@ -81,7 +81,7 @@ def _kernel(xm_ref, *rest, k, bl, act, has_halo, has_bias, has_clip, return_acc)
     if return_acc:
         o_ref[0] = acc
         return
-    y = acc.astype(jnp.float32) * xs_ref[0, 0] * ws_ref[...]
+    y = acc.astype(jnp.float32) * xs_ref[0, 0, 0] * ws_ref[...]
     if has_bias:
         y = y + b_ref[...]
     if act == "relu":
@@ -162,16 +162,19 @@ def conv1d_fused_q(
         )
         # Activation scale: one scalar per batch row (a per-tensor scale is
         # broadcast), so each grid step reads its own sample's dequant scale
-        # — this is what lets co-batched streams quantise independently.
+        # — this is what lets co-batched streams quantise independently.  The
+        # row axis leads a (b, 1, 1) array so the (1, 1, 1) block's last two
+        # dims equal the array's, which the TPU lowering requires of a block
+        # that is not (8, 128)-aligned.
         xs = jnp.broadcast_to(
-            jnp.asarray(x_scale, jnp.float32).reshape(-1, 1), (b, 1)
+            jnp.asarray(x_scale, jnp.float32).reshape(-1, 1, 1), (b, 1, 1)
         )
         inputs += [
             xs,
             jnp.pad(ws, ((0, 0), (0, cout_p - cout)), constant_values=1.0),
         ]
         in_specs += [
-            pl.BlockSpec((1, 1), lambda bb, i, j: (bb, 0)),
+            pl.BlockSpec((1, 1, 1), lambda bb, i, j: (bb, 0, 0)),
             pl.BlockSpec((1, bn), lambda bb, i, j: (0, j)),
         ]
         if has_bias:

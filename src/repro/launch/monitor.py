@@ -30,6 +30,7 @@ if _shards is not None:
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import acoustic, features
 from repro.models import cnn1d
 from repro.serving.engine import MonitorEngine
@@ -143,6 +144,7 @@ def main(argv=None):
     ap.add_argument("--trained", action="store_true",
                     help="use the cached canonical detector artifact (mfcc20)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.feature is None:
         # --trained serves the cached mfcc20 artifact; an explicit other
         # feature would silently train a full canonical model on cache miss.

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.distributed.sharding import ShardingRules, use_rules
 from repro.launch.mesh import make_host_mesh
@@ -136,6 +137,7 @@ def main(argv=None):
              "dead requests",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -22,6 +22,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import PrefetchingLoader, synthetic_lm_batches
 from repro.distributed.sharding import ShardingRules, tree_shardings, use_rules
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--max-straggler-steps", type=int, default=10)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -127,7 +127,6 @@ def moe_fwd_a2a(p, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     ht = h.reshape(t, d)
     xres = x.reshape(t, d)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(ht_l, router, wi_g, wi_u, wo):
@@ -164,7 +163,7 @@ def moe_fwd_a2a(p, x: jax.Array, cfg: ArchConfig) -> jax.Array:
         return (got * gv[..., None].astype(got.dtype)).sum(axis=1)
 
     tok_spec = P(tok_axes if len(tok_axes) > 1 else tok_axes[0])
-    y = shard_map(
+    y = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -175,7 +174,7 @@ def moe_fwd_a2a(p, x: jax.Array, cfg: ArchConfig) -> jax.Array:
             P("model", None, None),
         ),
         out_specs=P(tok_spec[0], None),
-        check_rep=False,
+        check_vma=False,
     )(ht, p["router"], _deq(p["wi_gate"]), _deq(p["wi_up"]), _deq(p["wo"]))
     y = (xres + y.astype(x.dtype)).reshape(b, s, d)
     return constrain(y, ("batch", "seq", "embed"))

@@ -32,7 +32,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.data import features_jax
@@ -43,6 +42,10 @@ from repro.models.cnn1d import CNNConfig, _maxpool2
 from repro.serving.quantized_params import QuantizedParams, quantize_params
 
 
+#: fewest rows a forward computes (one f32 sublane tile); see _forward_quantized
+MIN_ROWS = 8
+
+
 def _quantizer(layer_mode: str):
     from repro.core.quantization import fxp8_quantize, int8_symmetric
 
@@ -51,10 +54,12 @@ def _quantizer(layer_mode: str):
 
 def _conv1d_float(x: jax.Array, w: jax.Array) -> jax.Array:
     """'same' 1D conv for the float layer modes; accumulates in fp32 even for
-    bf16 operands (the MXU's bf16-in/fp32-accumulate discipline)."""
+    bf16 operands (the MXU's bf16-in/fp32-accumulate discipline).  HIGHEST
+    keeps fp32 operands fp32 on the TPU, whose default is one bf16 pass."""
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(1,), padding="SAME",
         dimension_numbers=("NWC", "WIO", "NWC"),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -81,9 +86,14 @@ def _forward_quantized(
     # micro-batching windows from N independent streams triggers.  Row-wise
     # scales also make every row's result independent of its co-batch, which
     # is what the streaming engine's bitwise-parity guarantee rests on.  The
-    # float layer modes preserve the same row independence for free (conv and
-    # matmul rows never mix).
+    # float layer modes keep rows independent too (conv and matmul rows never
+    # mix), once a short batch runs padded with copies of its last row to
+    # MIN_ROWS: the TPU lowers a one-row float conv or dot unlike a batched
+    # one, and on a v5e that moved a row of the mixed artifact at B=1.
     act_axis = 0 if per_sample_acts else None
+    n_rows = x.shape[0]
+    if 0 < n_rows < MIN_ROWS:
+        x = jnp.pad(x, ((0, MIN_ROWS - n_rows), (0, 0)), mode="edge")
     bsz = x.shape[0]
     conv_modes, dense_modes = qp.layer_modes
     h = x[:, :, None].astype(jnp.float32)
@@ -135,7 +145,7 @@ def _forward_quantized(
             h = h + layer["b"]
             if act == "relu":
                 h = jnp.maximum(h, 0.0)
-    return ops.cordic_softmax(h, interpret=interpret)
+    return ops.cordic_softmax(h, interpret=interpret)[:n_rows]
 
 
 def _check_raw_windows(qp: QuantizedParams, x: jax.Array, feature_kind: str | None):
@@ -228,12 +238,12 @@ def _forward_sharded(
         per_sample_acts=per_sample_acts,
         raw_windows=raw_windows,
     )
-    return shard_map(
+    return jax.shard_map(
         fwd,
         mesh=mesh,
         in_specs=(P(), P(axis_name)),  # weights replicated, rows sharded
         out_specs=P(axis_name),
-        check_rep=False,
+        check_vma=False,
     )(qp, x)
 
 

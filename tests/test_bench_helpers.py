@@ -186,35 +186,38 @@ def test_bench_env_pins_before_jax_import_subprocess():
 
 def test_bench_env_step_marker_leg_with_mocked_accel(tmp_path, monkeypatch):
     """The TPU leg of ``bench_env.apply()`` — exercised without hardware by
-    pointing ``ACCEL_DEVICE_GLOB`` at a tmp path: the step-marker flag is
-    pinned exactly once (idempotent on re-apply), recorded in the state,
-    and absent again when the glob matches nothing."""
+    pointing the device globs at a tmp path: a host is recognised by an
+    /dev/accel* node or by a v5e PCI function, the fingerprint records it,
+    and no step-marker flag is pinned (the v5e runtime aborts on it)."""
     import os
 
     from benchmarks import bench_env
 
-    (tmp_path / "accel0").touch()
-    monkeypatch.setattr(bench_env, "ACCEL_DEVICE_GLOB",
-                        str(tmp_path / "accel*"))
+    accel = tmp_path / "dev"
+    pci = tmp_path / "pci"
+    accel.mkdir()
+    for name, vendor, device in [("nic", "0x1ae0", "0x0042"), ("tpu", "0x1ae0", "0x0063")]:
+        (pci / name).mkdir(parents=True)
+        (pci / name / "vendor").write_text(vendor + "\n")
+        (pci / name / "device").write_text(device + "\n")
+    monkeypatch.setattr(bench_env, "ACCEL_DEVICE_GLOB", str(accel / "accel*"))
+    monkeypatch.setattr(bench_env, "PCI_DEVICE_GLOB", str(pci / "*"))
     monkeypatch.setenv("XLA_FLAGS", "")
     saved = dict(bench_env._state)
     try:
         state = bench_env.apply(host_devices=1)
-        assert state["step_marker"] is True
-        flags = os.environ["XLA_FLAGS"].split()
-        assert bench_env.STEP_MARKER_FLAG in flags
-        bench_env.apply(host_devices=1)  # re-apply: no duplicate flag
-        assert os.environ["XLA_FLAGS"].split().count(
-            bench_env.STEP_MARKER_FLAG
-        ) == 1
+        assert state["tpu_host"] is True  # the v5e PCI function
+        assert os.environ["XLA_FLAGS"].split() == [
+            "--xla_force_host_platform_device_count=1"
+        ]
+        assert "step_marker" not in os.environ["XLA_FLAGS"]
 
-        # no-hardware leg: empty glob means no marker and no flag
-        monkeypatch.setattr(bench_env, "ACCEL_DEVICE_GLOB",
-                            str(tmp_path / "nothing*"))
-        monkeypatch.setenv("XLA_FLAGS", "")
-        state = bench_env.apply(host_devices=1)
-        assert state["step_marker"] is False
-        assert bench_env.STEP_MARKER_FLAG not in os.environ["XLA_FLAGS"]
+        # another Google PCI function alone (a NIC) is not a TPU host ...
+        monkeypatch.setattr(bench_env, "PCI_DEVICE_GLOB", str(pci / "nic"))
+        assert bench_env.apply(host_devices=1)["tpu_host"] is False
+        # ... but an accel node is
+        (accel / "accel0").touch()
+        assert bench_env.apply(host_devices=1)["tpu_host"] is True
     finally:
         bench_env._state.clear()
         bench_env._state.update(saved)

@@ -12,7 +12,8 @@ different strength:
 
 * **within the JAX path, feature bits are per-row.**  Row i of the output is
   bitwise-unchanged by co-batch permutation, silence padding, and batch-size
-  changes (``lax.map`` gives every row an identical fixed-shape program).
+  changes (``lax.map`` gives every projection row an identical fixed-shape
+  program, and every float mean is a fixed pairwise tree).
   This is the property the serving layer's streaming == batched == sharded
   guarantee rests on once the front-end is fused into the jitted program.
 
@@ -21,6 +22,7 @@ orthonormality) are re-run here against the JAX path's float32 constants.
 """
 import zlib
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -184,6 +186,19 @@ def test_row_independence_property(batch, seed):
     """Property form over random batch sizes/content (each example compiles
     fresh batch shapes for every kind — full-tier only)."""
     _assert_row_independent(batch, seed)
+
+
+@pytest.mark.parametrize(
+    "shape,axis",
+    # every (length, axis) the front-end averages over, odd lengths included
+    [((3, 1), -1), ((2, 51), -1), ((2, 12, 513), 1), ((2, 8, 6, 128), 2), ((4, 1096), -1)],
+)
+def test_pairwise_mean_is_the_mean(shape, axis):
+    x = np.random.default_rng(3).standard_normal(shape).astype(np.float32)
+    got = np.asarray(features_jax._pairwise_mean(jnp.asarray(x), axis=axis))
+    want = x.astype(np.float64).mean(axis=axis)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
 
 
 def test_numpy_oracle_constants_are_cached():

@@ -109,6 +109,23 @@ def test_pruned_physical_equals_masked_unpruned_bitwise(name, mode, policy):
     np.testing.assert_array_equal(p_pruned, p_masked)
 
 
+@pytest.mark.parametrize("name,mode,policy", PRECISION_CELLS)
+def test_short_batch_rows_equal_batched_rows(name, mode, policy):
+    """A batch below ``MIN_ROWS`` runs padded to it; its rows equal the same
+    rows of a full batch bitwise, in every precision cell (the adaptive slot
+    ladder dispatches batches of 1 and 2)."""
+    cfg, params = _small_detector()
+    qp = quantize_params(params, cfg, mode=mode, policy=policy)
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal((16, cfg.input_len)).astype(np.float32)
+    x *= (10.0 ** rng.uniform(-2, 2, size=(16, 1))).astype(np.float32)
+    full = np.asarray(accelerator_forward(qp, jnp.asarray(x), cfg))
+    for n in (1, 3):
+        short = np.asarray(accelerator_forward(qp, jnp.asarray(x[:n]), cfg))
+        assert short.shape == (n, cfg.n_classes)
+        np.testing.assert_array_equal(short, full[:n])
+
+
 @pytest.mark.parametrize("quant", [int8_symmetric, fxp8_quantize])
 def test_dense_prune_int32_accumulator_parity(quant):
     """Accumulator-level form of the guarantee: slicing dense rows physically
