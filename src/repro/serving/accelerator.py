@@ -29,6 +29,7 @@ mixed-precision artifacts unchanged (pinned by
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,9 @@ from repro.serving.quantized_params import QuantizedParams, quantize_params
 
 #: fewest rows a forward computes (one f32 sublane tile); see _forward_quantized
 MIN_ROWS = 8
+
+#: the named scopes of the forward's layers, as they appear in an op_name
+SCOPES = re.compile(r"^(frontend|conv\d+|flatten|dense\d+|softmax)$")
 
 
 def _quantizer(layer_mode: str):
@@ -74,12 +78,17 @@ def _forward_quantized(
     per_sample_acts: bool,
     raw_windows: bool = False,
 ) -> jax.Array:
+    # Every layer runs under a jax.named_scope (frontend, conv<i>, flatten,
+    # dense<i>, softmax; SCOPES matches them), so each compiled operation's
+    # op_name names the layer it computes and a device trace can be summed
+    # per layer (hlo_scopes).
     # Fused DSP front-end: with raw_windows the program starts at the
     # microphone samples — feature extraction runs in-graph (per-row, see
     # features_jax) ahead of the quantised datapath, so host feature work
     # never serializes with device dispatch.
     if raw_windows:
-        x = features_jax.feature_rows(x, qp.feature_kind)
+        with jax.named_scope("frontend"):
+            x = features_jax.feature_rows(x, qp.feature_kind)
     # Per-sample (row-wise) activation scales are the default: with one
     # per-tensor scale, a single loud sample crushes the quantisation
     # resolution of every co-batched quiet one — exactly the failure mode
@@ -92,60 +101,73 @@ def _forward_quantized(
     # one, and on a v5e that moved a row of the mixed artifact at B=1.
     act_axis = 0 if per_sample_acts else None
     n_rows = x.shape[0]
-    if 0 < n_rows < MIN_ROWS:
-        x = jnp.pad(x, ((0, MIN_ROWS - n_rows), (0, 0)), mode="edge")
-    bsz = x.shape[0]
     conv_modes, dense_modes = qp.layer_modes
-    h = x[:, :, None].astype(jnp.float32)
-    for layer, lmode in zip(qp.convs, conv_modes):
-        if lmode in ("int8", "fxp8"):
-            hq = _quantizer(lmode)(h, axis=act_axis)  # per-request act quant
-            h = ops.conv1d_fused_q(
-                hq.q,
-                layer["w"].q,
-                hq.scale.reshape(-1, 1) if per_sample_acts else hq.scale,
-                layer["w"].scale,
-                layer["b"],
-                act="relu",  # CORDIC ReLU == max(v, 0): fused into the epilogue
-                interpret=interpret,
-            )
-        else:
-            hin = h.astype(jnp.bfloat16) if lmode == "bf16" else h
-            h = jnp.maximum(_conv1d_float(hin, layer["w"]) + layer["b"], 0.0)
-        h = _maxpool2(h)
-    if qp.keep_frames is not None:
-        h = h[:, : qp.keep_frames, :]  # pruned artifact: boundary-frame trim
-    h = h.reshape(bsz, -1)
+    with jax.named_scope("conv0"):
+        if 0 < n_rows < MIN_ROWS:
+            x = jnp.pad(x, ((0, MIN_ROWS - n_rows), (0, 0)), mode="edge")
+        h = x[:, :, None].astype(jnp.float32)
+    bsz = x.shape[0]
+    for i, (layer, lmode) in enumerate(zip(qp.convs, conv_modes)):
+        with jax.named_scope(f"conv{i}"):
+            h = _conv_layer(h, layer, lmode, act_axis, per_sample_acts, interpret)
+    with jax.named_scope("flatten"):
+        if qp.keep_frames is not None:
+            h = h[:, : qp.keep_frames, :]  # pruned artifact: boundary-frame trim
+        h = h.reshape(bsz, -1)
     for i, (layer, lmode) in enumerate(zip(qp.denses, dense_modes)):
         act = "relu" if i < len(qp.denses) - 1 else None
-        if lmode in ("int8", "fxp8"):
-            hq = _quantizer(lmode)(h, axis=act_axis)
-            h = ops.quant_matmul(
-                hq.q,
-                layer["w"].q,
-                hq.scale.reshape(bsz if per_sample_acts else 1, 1),
-                layer["w"].scale.reshape(1, -1),
-                layer["b"],
-                act=act,
-                interpret=interpret,
-            )
-        else:
-            if lmode == "bf16":
-                h = jnp.einsum(
-                    "bk,kn->bn",
-                    h.astype(jnp.bfloat16),
-                    layer["w"],
-                    preferred_element_type=jnp.float32,
-                )
-            else:
-                h = jnp.einsum(
-                    "bk,kn->bn", h, layer["w"],
-                    precision=jax.lax.Precision.HIGHEST,
-                )
-            h = h + layer["b"]
-            if act == "relu":
-                h = jnp.maximum(h, 0.0)
-    return ops.cordic_softmax(h, interpret=interpret)[:n_rows]
+        with jax.named_scope(f"dense{i}"):
+            h = _dense_layer(h, layer, lmode, act, act_axis, per_sample_acts, interpret)
+    with jax.named_scope("softmax"):
+        return ops.cordic_softmax(h, interpret=interpret)[:n_rows]
+
+
+def _conv_layer(h, layer, lmode, act_axis, per_sample_acts, interpret):
+    """One conv layer of the forward: activation quantiser, kernel, max-pool."""
+    if lmode in ("int8", "fxp8"):
+        hq = _quantizer(lmode)(h, axis=act_axis)  # per-request act quant
+        h = ops.conv1d_fused_q(
+            hq.q,
+            layer["w"].q,
+            hq.scale.reshape(-1, 1) if per_sample_acts else hq.scale,
+            layer["w"].scale,
+            layer["b"],
+            act="relu",  # CORDIC ReLU == max(v, 0): fused into the epilogue
+            interpret=interpret,
+        )
+    else:
+        hin = h.astype(jnp.bfloat16) if lmode == "bf16" else h
+        h = jnp.maximum(_conv1d_float(hin, layer["w"]) + layer["b"], 0.0)
+    return _maxpool2(h)
+
+
+def _dense_layer(h, layer, lmode, act, act_axis, per_sample_acts, interpret):
+    """One dense layer of the forward; ``act`` is "relu" or None."""
+    if lmode in ("int8", "fxp8"):
+        hq = _quantizer(lmode)(h, axis=act_axis)
+        return ops.quant_matmul(
+            hq.q,
+            layer["w"].q,
+            hq.scale.reshape(h.shape[0] if per_sample_acts else 1, 1),
+            layer["w"].scale.reshape(1, -1),
+            layer["b"],
+            act=act,
+            interpret=interpret,
+        )
+    if lmode == "bf16":
+        h = jnp.einsum(
+            "bk,kn->bn",
+            h.astype(jnp.bfloat16),
+            layer["w"],
+            preferred_element_type=jnp.float32,
+        )
+    else:
+        h = jnp.einsum(
+            "bk,kn->bn", h, layer["w"],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+    h = h + layer["b"]
+    return jnp.maximum(h, 0.0) if act == "relu" else h
 
 
 def _check_raw_windows(qp: QuantizedParams, x: jax.Array, feature_kind: str | None):
@@ -345,6 +367,89 @@ def precompile_slot_shapes(
                 qp, x, cfg, interpret=interpret, raw_windows=raw_windows
             )
         out.block_until_ready()
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%([^,\s}]+)")
+
+
+def hlo_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: layer scope}`` of a compiled forward's HLO text.
+
+    An instruction's scope is the one :data:`SCOPES` component of its
+    ``op_name``.  One without (a fusion the compiler made for itself)
+    takes the scope that the computations it calls carry, where they carry
+    exactly one.  Instructions that trace to no layer (layout copies of the
+    parameters, buffer allocations) are left out."""
+    own: dict[str, str] = {}
+    calls: dict[str, list[str]] = {}  # instruction -> computations it calls
+    members: dict[str, list[str]] = {}  # computation -> its instructions
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            members[comp] = []
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        name = m.group(1)
+        members[comp].append(name)
+        calls[name] = _CALLS.findall(line)
+        on = _OP_NAME.search(line)
+        found = {p for p in on.group(1).split("/") if SCOPES.match(p)} if on else set()
+        if len(found) == 1:
+            own[name] = found.pop()
+
+    @functools.cache
+    def carried(c: str) -> frozenset:
+        out = set()
+        for name in members.get(c, ()):
+            if name in own:
+                out.add(own[name])
+            for callee in calls[name]:
+                out |= carried(callee)
+        return frozenset(out)
+
+    out = dict(own)
+    for name, callees in calls.items():
+        if name not in out and callees:
+            found = frozenset().union(*(carried(c) for c in callees))
+            if len(found) == 1:
+                (out[name],) = found
+    return out
+
+
+def forward_scopes(
+    qp: QuantizedParams,
+    cfg: CNNConfig,
+    slot_counts,
+    *,
+    row_width: int | None = None,
+    mesh: Mesh | None = None,
+    axis_name: str | None = None,
+    interpret: bool | None = None,
+    raw_windows: bool = False,
+) -> dict[str, str]:
+    """:func:`hlo_scopes` of the forward compiled at each batch (slot)
+    shape, as :func:`precompile_slot_shapes` builds it: the map from a
+    device trace's operation names to the forward's layers."""
+    if row_width is None:
+        row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
+    interp = resolve_interpret(interpret)
+    out: dict[str, str] = {}
+    for slots in sorted(set(int(s) for s in slot_counts)):
+        x = jax.ShapeDtypeStruct((slots, row_width), jnp.float32)
+        if mesh is not None:
+            axis = STREAM_AXIS if axis_name is None else axis_name
+            lowered = _forward_sharded.lower(qp, x, mesh, axis, interp, True, raw_windows)
+        else:
+            lowered = _forward_quantized.lower(qp, x, interp, True, raw_windows)
+        out.update(hlo_scopes(lowered.compile().as_text()))
+    return out
 
 
 def deviation_report(

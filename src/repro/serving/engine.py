@@ -52,6 +52,7 @@ from repro.models.cnn1d import CNNConfig
 from repro.serving.accelerator import (
     accelerator_forward,
     accelerator_forward_sharded,
+    forward_scopes,
     precompile_slot_shapes,
 )
 from repro.serving.batching import (
@@ -66,6 +67,7 @@ from repro.serving.quantized_params import (
     quantize_params,
     replicate_params,
 )
+from repro.serving.telemetry import Telemetry
 from repro.serving.tracker import TrackEvent, VectorTemporalTracker
 
 
@@ -460,7 +462,7 @@ class MonitorEngine:
         self._pool = BlockPool(self._in_width, inflight)
         self._core = DispatchCore(
             submit=self._submit_rows,
-            harvest=lambda buf: np.asarray(buf.block_until_ready()),
+            harvest=self._harvest,
             slot_policy=self.slot_policy,
             inflight=inflight,
         )
@@ -492,6 +494,13 @@ class MonitorEngine:
         self.served_windows = np.zeros(n_streams, np.int64)
         self.deferred_windows = np.zeros(n_streams, np.int64)
         self.refused_chunks = np.zeros(n_streams, np.int64)
+        # Host spans at every boundary of push/step (off by default; not
+        # part of snapshot()): engine.push counts samples, engine.step the
+        # windows scored, engine.gather the bytes gathered, engine.pack the
+        # live rows, engine.put the bytes handed to the device,
+        # engine.launch and engine.wait the slots, engine.tracker and
+        # engine.commit the windows.
+        self.telemetry = Telemetry()
 
     # -- ingest --------------------------------------------------------------
 
@@ -502,6 +511,15 @@ class MonitorEngine:
         streams ever pushed are admitted; chunks for later streams — and for
         streams the engine has evicted — are refused (counted in
         ``refused_chunks``, returns 0) without touching any ring."""
+        tel = self.telemetry
+        if tel.on:
+            i = tel.open("engine.push", self.rounds)
+            dropped = self._push(stream, samples)
+            tel.close(i, np.asarray(samples).size)
+            return dropped
+        return self._push(stream, samples)
+
+    def _push(self, stream: int, samples: np.ndarray) -> int:
         if not 0 <= stream < self.n_streams:
             raise ValueError(
                 f"stream index {stream} out of range for an engine with "
@@ -597,22 +615,48 @@ class MonitorEngine:
     def _submit(self, block: np.ndarray) -> jax.Array:
         """Dispatch one slot block; returns the in-flight device buffer
         (jax dispatch is async — this does not wait for the result)."""
+        tel = self.telemetry
+        if tel.on:
+            i = tel.open("engine.put")
         x = jnp.asarray(block)
+        if tel.on:
+            tel.close(i, block.nbytes)
+            i = tel.open("engine.launch")
         raw = self.on_device_features
         if self._mesh is not None:
-            return accelerator_forward_sharded(
+            out = accelerator_forward_sharded(
                 self._qp, x, self.cfg, mesh=self._mesh,
                 axis_name=self._mesh_axis, interpret=self._interpret,
                 raw_windows=raw,
             )
-        return accelerator_forward(
-            self._qp, x, self.cfg, interpret=self._interpret, raw_windows=raw
-        )
+        else:
+            out = accelerator_forward(
+                self._qp, x, self.cfg, interpret=self._interpret, raw_windows=raw
+            )
+        if tel.on:
+            tel.close(i, block.shape[0])
+        return out
 
     def _submit_rows(self, rows, slots: int) -> jax.Array:
         """DispatchCore submit hook: pack live rows into the next rotation
         buffer of the chosen slot shape and dispatch it."""
-        return self._submit(self._pool.pack(rows, slots))
+        tel = self.telemetry
+        if tel.on:
+            i = tel.open("engine.pack")
+        block = self._pool.pack(rows, slots)
+        if tel.on:
+            tel.close(i, len(rows))
+        return self._submit(block)
+
+    def _harvest(self, buf: jax.Array) -> np.ndarray:
+        """DispatchCore harvest hook: wait for one block, copy it to host."""
+        tel = self.telemetry
+        if tel.on:
+            i = tel.open("engine.wait")
+        out = np.asarray(buf.block_until_ready())
+        if tel.on:
+            tel.close(i, buf.shape[0])
+        return out
 
     def _forward(self, rows: np.ndarray) -> np.ndarray:
         """Micro-batch (n, row_width) inputs — features, or raw windows when
@@ -639,6 +683,22 @@ class MonitorEngine:
         )
         return self.slot_policy.ladder
 
+    def op_scopes(self) -> dict[str, str]:
+        """``{HLO instruction name: layer scope}`` of the forward compiled at
+        every dispatchable slot shape (``frontend``, ``conv<i>``,
+        ``flatten``, ``dense<i>``, ``softmax``): what maps the operations of
+        a device trace to the layers of the model."""
+        return forward_scopes(
+            self._qp,
+            self.cfg,
+            self.slot_policy.ladder,
+            row_width=self._in_width,
+            mesh=self._mesh,
+            axis_name=self._mesh_axis,
+            interpret=self._interpret,
+            raw_windows=self.on_device_features,
+        )
+
     def step(self) -> list[WindowScore]:
         """Score one round over the admitted backlog.
 
@@ -662,6 +722,15 @@ class MonitorEngine:
         Returns the per-window scores of this round (empty when no admitted
         stream had a complete window buffered).
         """
+        tel = self.telemetry
+        if tel.on:
+            i = tel.open("engine.step", self.rounds, step=True)
+            out = self._step(tel)
+            tel.close(i, len(out))
+            return out
+        return self._step(tel)
+
+    def _step(self, tel: Telemetry) -> list[WindowScore]:
         adm = self.admission
         cand = np.flatnonzero((self._ready_counts > 0) & self._admitted)
         if cand.size == 0:
@@ -673,6 +742,8 @@ class MonitorEngine:
         # consecutive windows starting at offs[i].
         offs = np.zeros(cand.size, np.int64)
         np.cumsum(alloc[:-1], out=offs[1:])
+        if tel.on:
+            i = tel.open("engine.gather")
         wins = [
             self._rings[s].peek_windows(int(k))
             for s, k in zip(cand, alloc)
@@ -683,7 +754,12 @@ class MonitorEngine:
             rows = stacked  # raw windows; the front-end runs in-graph
         else:
             rows = features.batch_features(stacked, self.feature_kind)
+        if tel.on:
+            tel.close(i, stacked.nbytes)
         p_uav = self._forward(rows)[:, 1]  # may raise: nothing committed yet
+        n_win = int(alloc.sum())
+        if tel.on:
+            i = tel.open("engine.tracker")
         # Tracker rounds go depth by depth — every served stream's d-th
         # window lands in one masked vector update — so each stream's
         # probability sequence reaches its EMA in exactly push order and the
@@ -707,13 +783,16 @@ class MonitorEngine:
                 )
                 for s in sel
             )
+        if tel.on:
+            tel.close(i, n_win)
+            i = tel.open("engine.commit")
         # Commit: consume the scored windows only now that the forward and
         # the tracker rounds all succeeded.
         for s, k in zip(cand, alloc):
             for _ in range(int(k)):
                 self._rings[s].advance()
             self._ready_counts[s] = self._rings[s].ready
-        self.windows_scored += int(alloc.sum())
+        self.windows_scored += n_win
         self.rounds += 1
         self.served_windows[cand] += alloc
         self.deferred_windows[cand] += ready - alloc
@@ -731,6 +810,8 @@ class MonitorEngine:
             for s in evict:
                 self._admitted[s] = False
                 self._pending_evictions.append(int(s))
+        if tel.on:
+            tel.close(i, n_win)
         return out
 
     def drain(self) -> list[WindowScore]:

@@ -9,6 +9,8 @@ Pallas kernel survived as a ``tpu_custom_call``.  The topology is described
 inside a module fixture, never at import time: only one process may load
 the TPU library, and pytest-xdist workers import every test module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +23,7 @@ from repro.kernels.conv1d_fused import conv1d_fused_q
 from repro.kernels.cordic_act import cordic_softmax
 from repro.kernels.quant_matmul import quant_matmul
 from repro.models import cnn1d
-from repro.serving.accelerator import _forward_quantized
+from repro.serving.accelerator import SCOPES, _forward_quantized, hlo_scopes
 from repro.serving.quantized_params import quantize_params
 
 CFG = cnn1d.CNNConfig()  # published widths: M=1096, channels 64/128/256
@@ -109,14 +111,48 @@ def _artifact(params, name):
     )
 
 
+@pytest.fixture(scope="module")
+def served_forward(one_chip, params):
+    """The whole served program at 64 slots, compiled once per artifact."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            qp = _artifact(params, name)
+            qp_spec = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), qp)
+            cache[name] = _forward_quantized.lower(
+                qp_spec, _spec(one_chip, (SLOTS, N_SAMPLES), jnp.float32),
+                interpret=False, per_sample_acts=True, raw_windows=True,
+            ).compile()
+        return cache[name]
+
+    return get
+
+
 @pytest.mark.parametrize("name", ["int8", "pruned_mixed"])
-def test_forward_from_raw_windows_compiles(one_chip, params, name):
+def test_forward_from_raw_windows_compiles(served_forward, name):
     """The whole served program at 64 slots: on-device mfcc20 front-end,
     W8A8 kernels (and the float layers of the mixed policy), CORDIC head."""
-    qp = _artifact(params, name)
-    qp_spec = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), qp)
-    compiled = _forward_quantized.lower(
-        qp_spec, _spec(one_chip, (SLOTS, N_SAMPLES), jnp.float32),
-        interpret=False, per_sample_acts=True, raw_windows=True,
-    ).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(served_forward(name))
+
+
+@pytest.mark.parametrize("name", ["int8", "pruned_mixed"])
+def test_forward_ops_carry_one_scope_on_tpu(served_forward, name):
+    """As the chip compiles the served program, every operation that came
+    from the forward names one layer, and every Pallas kernel maps to a
+    layer (a device trace's operations are summed per layer through this
+    map)."""
+    text = served_forward(name).as_text()
+    for line in text.splitlines():
+        on = re.search(r'op_name="(jit\([^"]*)"', line)
+        if on and re.search(r" (fusion|convolution|dot|custom-call|while|reduce-window)\(", line):
+            assert len([p for p in on.group(1).split("/") if SCOPES.match(p)]) == 1, line
+    scopes = hlo_scopes(text)
+    kernels = re.findall(r"^\s+(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                         text, re.M)
+    layers = {"frontend", "conv0", "conv1", "conv2", "dense0", "dense1", "softmax"}
+    # int8: 3 conv, 2 dense, softmax; pruned-mixed runs conv0 and dense1 in float
+    assert len(kernels) == {"int8": 6, "pruned_mixed": 4}[name]
+    assert {scopes[k] for k in kernels} <= layers
+    # flatten is a bitcast unless the pruned artifact trims frames first
+    assert layers <= set(scopes.values()) <= layers | {"flatten"}
