@@ -161,9 +161,16 @@ class BlockPool:
     def pack(self, rows: Sequence[np.ndarray] | np.ndarray, slots: int) -> np.ndarray:
         """Copy ``rows`` into the next rotation buffer of shape ``(slots,
         width)``; dead-slot tails carry zeros (silence)."""
-        n = len(rows)
-        if n > slots:
-            raise ValueError(f"{n} rows do not fit {slots} slots")
+        block = self.buffer(slots, len(rows))
+        block[: len(rows)] = rows
+        return block
+
+    def buffer(self, slots: int, live: int) -> np.ndarray:
+        """The next rotation buffer of shape ``(slots, width)`` for a caller
+        that writes its first ``live`` rows itself; the dead-slot tail
+        ``[live:]`` already carries zeros (silence)."""
+        if live > slots:
+            raise ValueError(f"{live} rows do not fit {slots} slots")
         pool = self._pools.get(slots)
         if pool is None:
             pool = [
@@ -175,9 +182,8 @@ class BlockPool:
         i = self._next[slots]
         self._next[slots] = (i + 1) % self.depth
         block = pool[i]
-        block[:n] = rows
-        if n < slots:
-            block[n:] = 0.0  # dead slots carry silence
+        if live < slots:
+            block[live:] = 0.0  # dead slots carry silence
         return block
 
 

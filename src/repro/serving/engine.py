@@ -148,8 +148,7 @@ class StreamRing:
         window."""
         if self._w - self._r < self.window:
             return None
-        idx = (self._r + np.arange(self.window)) % self.capacity
-        return self._buf[idx].copy()
+        return self.peek_windows(1)[0]
 
     def peek_windows(self, k: int) -> np.ndarray:
         """The next ``k`` hop-aligned windows *without* consuming them, as a
@@ -158,14 +157,32 @@ class StreamRing:
         fewer than ``k`` complete windows are buffered."""
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
-        if self.ready < k:
-            raise ValueError(f"{k} window(s) requested, only {self.ready} ready")
-        idx = (
-            self._r
-            + np.arange(k)[:, None] * self.hop
-            + np.arange(self.window)[None, :]
-        ) % self.capacity
-        return self._buf[idx]  # fancy indexing: already a copy
+        return self.copy_windows(np.empty((k, self.window), np.float32))
+
+    def copy_windows(self, out: np.ndarray, first: int = 0) -> np.ndarray:
+        """Copy the ``len(out)`` hop-aligned windows that start ``first``
+        hops past the read head into the rows of ``out`` (a caller-given
+        ``(k, window)`` array, e.g. rows of a dispatch block), *without*
+        consuming them; returns ``out``.  Each window is one contiguous
+        slice of the buffer, two when it wraps the buffer's end — no index
+        array.  Raises if those windows are not all buffered."""
+        k = len(out)
+        if first < 0 or self.ready < first + k:
+            raise ValueError(
+                f"windows {first}..{first + k - 1} requested, only "
+                f"{self.ready} ready"
+            )
+        buf, cap, win, hop = self._buf, self.capacity, self.window, self.hop
+        pos = (self._r + first * hop) % cap
+        for j in range(k):
+            end = pos + win
+            if end <= cap:
+                out[j] = buf[pos:end]
+            else:  # wraps the buffer's end
+                out[j, : cap - pos] = buf[pos:]
+                out[j, cap - pos :] = buf[: end - cap]
+            pos = (pos + hop) % cap
+        return out
 
     def advance(self):
         """Consume one hop off the front (commit the last peeked window)."""
@@ -496,8 +513,10 @@ class MonitorEngine:
         self.refused_chunks = np.zeros(n_streams, np.int64)
         # Host spans at every boundary of push/step (off by default; not
         # part of snapshot()): engine.push counts samples, engine.step the
-        # windows scored, engine.gather the bytes gathered, engine.pack the
-        # live rows, engine.put the bytes handed to the device,
+        # windows scored, engine.gather (the round's window plan, or the
+        # stacked windows and host features when off-device) the bytes the
+        # round reads, engine.pack (filling the blocks) the live rows,
+        # engine.put the bytes handed to the device,
         # engine.launch and engine.wait the slots, engine.tracker and
         # engine.commit the windows.
         self.telemetry = Telemetry()
@@ -637,15 +656,23 @@ class MonitorEngine:
             tel.close(i, block.shape[0])
         return out
 
-    def _submit_rows(self, rows, slots: int) -> jax.Array:
-        """DispatchCore submit hook: pack live rows into the next rotation
-        buffer of the chosen slot shape and dispatch it."""
+    def _submit_rows(self, items, slots: int) -> jax.Array:
+        """DispatchCore submit hook: fill the next rotation buffer of the
+        chosen slot shape with the live items and dispatch it.  With the
+        front-end on the device an item is a ``(ring, depth)`` pair of the
+        round's window plan, and the ring copies that window straight into
+        its row of the block; otherwise an item is a feature row."""
         tel = self.telemetry
         if tel.on:
             i = tel.open("engine.pack")
-        block = self._pool.pack(rows, slots)
+        if self.on_device_features:
+            block = self._pool.buffer(slots, len(items))
+            for j, (ring, depth) in enumerate(items):
+                ring.copy_windows(block[j : j + 1], depth)
+        else:
+            block = self._pool.pack(items, slots)
         if tel.on:
-            tel.close(i, len(rows))
+            tel.close(i, len(items))
         return self._submit(block)
 
     def _harvest(self, buf: jax.Array) -> np.ndarray:
@@ -658,14 +685,14 @@ class MonitorEngine:
             tel.close(i, buf.shape[0])
         return out
 
-    def _forward(self, rows: np.ndarray) -> np.ndarray:
-        """Micro-batch (n, row_width) inputs — features, or raw windows when
-        the front-end is fused — through the shared dispatch core: the slot
-        policy picks each block's shape (fixed ``batch_slots``, or the
-        adaptive ladder), blocks come from the preallocated buffer rotation,
-        and up to ``inflight`` blocks overlap on device with harvest-time
-        ``block_until_ready``."""
-        return np.stack(self._core.dispatch(list(rows)))
+    def _forward(self, items) -> np.ndarray:
+        """Micro-batch the round's items — ``(n, row_width)`` feature rows,
+        or the ``(ring, depth)`` window plan when the front-end is fused —
+        through the shared dispatch core: the slot policy picks each block's
+        shape (fixed ``batch_slots``, or the adaptive ladder), blocks come
+        from the preallocated buffer rotation, and up to ``inflight`` blocks
+        overlap on device with harvest-time ``block_until_ready``."""
+        return np.stack(self._core.dispatch(list(items)))
 
     def precompile(self) -> tuple[int, ...]:
         """Trace the jitted forward once per dispatchable slot shape (the
@@ -738,26 +765,30 @@ class MonitorEngine:
         ready = self._ready_counts[cand]
         want = np.minimum(ready, adm.max_per_stream_per_round)
         alloc = fair_allocation(want, adm.round_budget)
-        # Gather stream-major: stream cand[i] contributes alloc[i]
-        # consecutive windows starting at offs[i].
+        # Stream-major: stream cand[i] contributes alloc[i] consecutive
+        # windows starting at offs[i].
         offs = np.zeros(cand.size, np.int64)
         np.cumsum(alloc[:-1], out=offs[1:])
+        n_win = int(alloc.sum())
         if tel.on:
             i = tel.open("engine.gather")
-        wins = [
-            self._rings[s].peek_windows(int(k))
-            for s, k in zip(cand, alloc)
-            if k
-        ]
-        stacked = np.concatenate(wins, axis=0)
+        rings = self._rings
         if self.on_device_features:
-            rows = stacked  # raw windows; the front-end runs in-graph
+            # The window plan: each block's rows are copied ring -> block
+            # just before that block is submitted (``_submit_rows``).
+            items = [
+                (rings[s], d)
+                for s, k in zip(cand.tolist(), alloc.tolist())
+                for d in range(k)
+            ]
         else:
-            rows = features.batch_features(stacked, self.feature_kind)
+            stacked = np.concatenate(
+                [rings[s].peek_windows(int(k)) for s, k in zip(cand, alloc) if k]
+            )
+            items = features.batch_features(stacked, self.feature_kind)
         if tel.on:
-            tel.close(i, stacked.nbytes)
-        p_uav = self._forward(rows)[:, 1]  # may raise: nothing committed yet
-        n_win = int(alloc.sum())
+            tel.close(i, n_win * self.window * 4)  # float32 bytes the round reads
+        p_uav = self._forward(items)[:, 1]  # may raise: nothing committed yet
         if tel.on:
             i = tel.open("engine.tracker")
         # Tracker rounds go depth by depth — every served stream's d-th

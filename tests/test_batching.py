@@ -112,6 +112,23 @@ def test_block_pool_zeroes_dead_tail():
     np.testing.assert_array_equal(partial[1:], np.zeros((2, 2), np.float32))
 
 
+def test_block_pool_buffer_shares_the_rotation_and_zeroes_the_dead_tail():
+    """``buffer`` hands out the same ``inflight + 1`` rotation as ``pack``,
+    for a caller that fills the live rows itself: a reused buffer's dead
+    tail is silence again, its live rows are left to the caller."""
+    pool = BlockPool(width=2, inflight=1)
+    a = pool.pack([np.full(2, 5.0, np.float32)] * 3, 3)
+    b = pool.buffer(3, 3)
+    b[:] = 9.0
+    again = pool.buffer(3, 1)
+    assert again is a and b is not a  # depth 2: the rotation came round
+    np.testing.assert_array_equal(again[0], np.full(2, 5.0, np.float32))
+    np.testing.assert_array_equal(again[1:], np.zeros((2, 2), np.float32))
+    assert pool.pack([np.ones(2, np.float32)], 3) is b
+    with pytest.raises(ValueError, match="do not fit"):
+        pool.buffer(3, 4)
+
+
 def test_block_pool_shapes_rotate_independently():
     pool = BlockPool(width=1, inflight=1)
     a = pool.pack([np.zeros(1, np.float32)], 2)

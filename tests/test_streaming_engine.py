@@ -89,6 +89,62 @@ def test_ring_giant_push_keeps_tail():
     np.testing.assert_array_equal(r.pop_window(), np.arange(40, 50))
 
 
+def _index_gather(ring, k, first=0):
+    """The ring's windows by a modulo fancy index over its buffer: the
+    oracle the contiguous-slice copy must match bitwise."""
+    idx = (
+        ring._r
+        + (first + np.arange(k))[:, None] * ring.hop
+        + np.arange(ring.window)[None, :]
+    ) % ring.capacity
+    return ring._buf[idx]
+
+
+# (window, hop, capacity_windows, pushes, advances, pushes after)
+RING_CASES = {
+    "hop_eq_window": (8, 8, 4, [20], 0, []),
+    "overlapping_hop": (8, 3, 4, [17], 0, []),
+    "wraps_ring_end": (8, 5, 3, [18], 2, [10]),
+    "k_gt_1_full_ring": (8, 8, 8, [64], 0, []),
+    "head_after_overflow": (8, 4, 3, [11, 29], 0, [3]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_ring_copy_windows_equals_index_gather(case):
+    window, hop, cap_w, pushes, advances, later = RING_CASES[case]
+    r = StreamRing(window=window, hop=hop, capacity_windows=cap_w)
+    n = 0
+    for size in pushes:
+        r.push(np.arange(n, n + size, dtype=np.float32))
+        n += size
+    for _ in range(advances):
+        r.advance()
+    for size in later:
+        r.push(np.arange(n, n + size, dtype=np.float32))
+        n += size
+    ready, heads = r.ready, (r._r, r._w)
+    assert ready >= 2
+    starts = [(r._r + d * hop) % r.capacity for d in range(ready)]
+    if case == "wraps_ring_end":
+        assert any(s + window > r.capacity for s in starts)
+    if case == "head_after_overflow":
+        assert r.dropped > 0 and r._r % r.capacity != 0
+    for first in range(ready):
+        for k in range(1, ready - first + 1):
+            out = np.full((k, window), np.nan, np.float32)
+            assert r.copy_windows(out, first) is out
+            np.testing.assert_array_equal(out, _index_gather(r, k, first))
+    for k in range(1, ready + 1):
+        np.testing.assert_array_equal(r.peek_windows(k), _index_gather(r, k))
+    np.testing.assert_array_equal(r.peek_window(), _index_gather(r, 1)[0])
+    with pytest.raises(ValueError, match="ready"):
+        r.copy_windows(np.empty((2, window), np.float32), ready - 1)
+    with pytest.raises(ValueError, match="ready"):
+        r.peek_windows(ready + 1)
+    assert (r._r, r._w) == heads and r.ready == ready  # nothing consumed
+
+
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
@@ -312,6 +368,148 @@ def test_engine_on_device_equals_manual_two_stage():
     two_stage = np.asarray(accelerator_forward(engine._qp, feats, cfg))[:, 1]
     got = np.asarray([ws.p_uav for ws in sorted(scored, key=lambda w: w.stream)])
     np.testing.assert_array_equal(got, two_stage.astype(np.float64))
+
+
+@pytest.mark.parametrize("per_round", [1, 3])
+def test_on_device_rounds_equal_forward_of_peeked_windows(per_round):
+    """With the front-end on the device each ring copies its windows
+    straight into the dispatch blocks: over several rounds the scores stay
+    bitwise equal to one raw-window forward of the rings' peeked windows,
+    stacked stream-major in depth order — with more than one block a round,
+    a partial last block with dead slots, and several windows per stream."""
+    cfg, params = _small_detector()
+    rng = np.random.default_rng(17)
+    n_streams = 5
+    engine = MonitorEngine(
+        params, cfg, n_streams=n_streams, feature_kind="zcr",
+        on_device_features=True, batch_slots=2, capacity_windows=4,
+        admission=AdmissionPolicy(max_per_stream_per_round=per_round), **TRACK_KW,
+    )
+    multi_block = dead_slots = deep = 0
+    for _ in range(4):
+        for s in range(n_streams):
+            n = int(rng.uniform(0.6, 2.2) * features.N_SAMPLES)
+            engine.push(s, rng.standard_normal(n).astype(np.float32))
+        take = np.minimum(engine.ready_windows(), per_round)
+        stacked = np.concatenate(
+            [engine._rings[s].peek_windows(int(k)) for s, k in enumerate(take) if k]
+        )
+        want = np.asarray(
+            accelerator_forward(engine._qp, jnp.asarray(stacked), cfg, raw_windows=True)
+        )[:, 1]
+        calls, padded = engine.forward_calls, engine.padded_slots
+        out = engine.step()
+        got = [w.p_uav for w in sorted(out, key=lambda w: (w.stream, w.window_idx))]
+        np.testing.assert_array_equal(np.asarray(got), want.astype(np.float64))
+        multi_block += engine.forward_calls - calls > 1
+        dead_slots += engine.padded_slots > padded
+        deep += int(take.max()) > 1
+    assert multi_block and dead_slots
+    assert bool(deep) == (per_round > 1)
+
+
+def _round_state(engine):
+    return (
+        [(r._r, r._w) for r in engine._rings],
+        engine.ready_windows(),
+        engine.tracker.state_dict(),
+        engine.windows_scored,
+        engine.rounds,
+    )
+
+
+def _assert_same_state(a, b):
+    assert a[0] == b[0] and a[3:] == b[3:]
+    np.testing.assert_array_equal(a[1], b[1])
+    assert a[2].keys() == b[2].keys()
+    for key in a[2]:
+        if key == "events":
+            assert a[2][key] == b[2][key]
+        else:
+            np.testing.assert_array_equal(a[2][key], b[2][key])
+
+
+@pytest.mark.parametrize("fault", ["fault_hook", "second_block_submit"])
+def test_on_device_failed_round_is_untouched_and_retries_identically(fault):
+    """A round that raises — in the fault seam before anything is copied,
+    or in the submit of its second block after the first block's windows
+    were copied and sent — leaves rings, ready counts and tracker as they
+    were, and the retried round scores exactly what a clean engine does."""
+    cfg, params = _small_detector()
+    n_streams = 3
+
+    def build():
+        engine = MonitorEngine(
+            params, cfg, n_streams=n_streams, feature_kind="zcr",
+            on_device_features=True, batch_slots=2,
+            admission=AdmissionPolicy(max_per_stream_per_round=2), **TRACK_KW,
+        )
+        rng = np.random.default_rng(23)
+        for s in range(n_streams):
+            n = int((2.4 + 0.5 * s) * features.N_SAMPLES)
+            engine.push(s, rng.standard_normal(n).astype(np.float32))
+        return engine
+
+    def scores(out):
+        return [(w.stream, w.window_idx, w.p_uav, w.smoothed, w.active) for w in out]
+
+    clean = build()
+    want = [scores(clean.step()), scores(clean.step())]
+    engine = build()
+    before = _round_state(engine)
+    if fault == "fault_hook":
+        def boom(items):
+            raise RuntimeError("injected")
+        engine.fault_hook = boom
+    else:
+        real_submit, calls = engine._submit, []
+
+        def submit(block):
+            calls.append(block.shape[0])
+            if len(calls) == 2:
+                raise RuntimeError("injected")
+            return real_submit(block)
+        engine._submit = submit
+    with pytest.raises(RuntimeError, match="injected"):
+        engine.step()
+    _assert_same_state(_round_state(engine), before)
+    engine.fault_hook = None
+    engine.__dict__.pop("_submit", None)
+    assert [scores(engine.step()), scores(engine.step())] == want
+
+
+def test_on_device_round_copies_each_window_once_into_its_block(monkeypatch):
+    """The fused-front-end round stacks nothing (no ``peek_windows``): each
+    scored window is copied once, by its ring, into a row of the dispatch
+    block that is sent."""
+    cfg, params = _small_detector()
+    engine = MonitorEngine(
+        params, cfg, n_streams=5, feature_kind="zcr", on_device_features=True,
+        batch_slots=2,
+    )
+    rng = np.random.default_rng(8)
+    for s in range(5):
+        engine.push(s, rng.standard_normal(features.N_SAMPLES).astype(np.float32))
+    copies, sent = [], []
+    real_copy = StreamRing.copy_windows
+
+    def copy_windows(ring, out, first=0):
+        copies.append((ring, len(out), out.base))
+        return real_copy(ring, out, first)
+
+    def no_stack(*a, **k):
+        raise AssertionError("the round stacked its windows")
+
+    monkeypatch.setattr(StreamRing, "copy_windows", copy_windows)
+    monkeypatch.setattr(StreamRing, "peek_windows", no_stack)
+    real_submit = engine._submit
+    engine._submit = lambda block: sent.append(block) or real_submit(block)
+    out = engine.step()
+    monkeypatch.undo()
+    assert len(out) == 5 and engine.padded_slots == 1
+    assert [c[0] for c in copies] == engine._rings  # stream-major, once each
+    assert all(n == 1 for _, n, _ in copies)
+    assert [id(c[2]) for c in copies] == [id(sent[j // 2]) for j in range(5)]
 
 
 def test_engine_rejects_artifact_without_feature_kind():

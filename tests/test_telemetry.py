@@ -34,11 +34,12 @@ def detector():
     return cfg, cnn1d.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _serve(detector, on=False, annotate=False, rounds=4, fault_round=None):
+def _serve(detector, on=False, annotate=False, rounds=4, fault_round=None,
+           on_device=True):
     """A tiny engine fed seeded uneven chunks; returns it and every score."""
     cfg, params = detector
     eng = MonitorEngine(params, cfg, n_streams=N_STREAMS, feature_kind="zcr",
-                        on_device_features=True, batch_slots=SLOTS)
+                        on_device_features=on_device, batch_slots=SLOTS)
     eng.telemetry.on, eng.telemetry.annotate = on, annotate
     rng = np.random.default_rng(3)
     out = []
@@ -101,8 +102,24 @@ def test_spans_nest_per_round_with_fixed_counts(detector):
     assert sum(by["engine.tracker"]) == sum(by["engine.commit"]) == len(out)
     assert sum(by["engine.gather"]) == len(out) * width * 4  # float32 windows
     assert sum(by["engine.pack"]) == len(out)  # live rows
+    assert len(by["engine.pack"]) == eng.forward_calls  # one block fill each
     assert by["engine.launch"] == by["engine.wait"] == [SLOTS] * eng.forward_calls
     assert by["engine.put"] == [SLOTS * width * 4] * eng.forward_calls
+
+
+def test_off_device_round_keeps_the_span_counts(detector):
+    """Host features: the round still stacks its windows (engine.gather
+    counts the raw bytes read) and packs feature rows (engine.pack counts
+    the live rows, one span a block); the device gets feature blocks."""
+    eng, out = _serve(detector, on=True, on_device=False)
+    by = collections.defaultdict(list)
+    for name, *_, count in eng.telemetry.spans:
+        by[name].append(count)
+    assert out and sum(by["engine.gather"]) == len(out) * features.N_SAMPLES * 4
+    assert sum(by["engine.pack"]) == len(out)
+    assert len(by["engine.pack"]) == eng.forward_calls
+    cfg, _ = detector
+    assert by["engine.put"] == [SLOTS * cfg.input_len * 4] * eng.forward_calls
 
 
 def test_self_times_sum_to_push_and_step(detector):
