@@ -1,15 +1,22 @@
 """Finds every piece of a cell by the names ``BENCHMARK.json`` gives.
 
-* a configuration: ``chipbench/configs/<config>.json``, and the plain
-  reference module that file names (``chipbench/configs/<reference>.py``);
+* a configuration: ``chipbench/configs/<config>.json``; the family module
+  that file names (``chipbench/families/<family>.py``), which defines
+  ``weights(model, seed)`` (the float weights, from ``weights.key(seed)``),
+  ``engine(cell, params)`` (the program under test, through its public
+  constructor) and ``layers(config)`` (``[(layer, operations per window,
+  stated precision)]``); and the plain reference module that file names
+  (``chipbench/configs/<reference>.py``), whose one entry point for the
+  check is ``p_uav(params, windows, config, modes)`` (the probability of
+  "UAV" per window), beside ``control_modes`` and ``track``;
 * a traffic mix: ``chipbench/traffic/<traffic>.json``, and the load loop
   its ``loop`` names (``chipbench/loops/<loop>.py``, see ``load.py``);
 * a metric: ``chipbench/metrics/<metric>.py``, which defines ``read(r)``;
 * a kernel's operations and bytes: ``chipbench/kernels/<kernel>.py``;
 * the peaks of a device: ``chipbench/peaks.json``, keyed by ``device_kind``.
 
-New cells, mixes, loops, metrics and kernels are new files plus new entries in
-``BENCHMARK.json``; nothing here names one of them.
+New cells, families, mixes, loops, metrics and kernels are new files plus new
+entries in ``BENCHMARK.json``; nothing here names one of them.
 """
 from __future__ import annotations
 
@@ -44,6 +51,7 @@ class Cell:
     chips: int
     config: dict
     traffic: dict
+    family: object  # the configuration's family module
     reference: object  # the configuration's reference module
     end_to_end: list  # metric entries of BENCHMARK.json this cell reports
     per_layer: list
@@ -80,10 +88,16 @@ def make_cell(name: str, config: str, traffic: str, chips: int, end_to_end: list
         chips=chips,
         config=cfg,
         traffic=_json(here / "traffic" / f"{traffic}.json"),
+        family=family(cfg["family"], root),
         reference=_module(here / "configs" / f"{cfg['reference']}.py"),
         end_to_end=end_to_end,
         per_layer=per_layer,
     )
+
+
+def family(name: str, root: Path = ROOT):
+    """The module of a model family, which defines ``weights``, ``engine`` and ``layers``."""
+    return _module(root / "chipbench" / "families" / f"{name}.py")
 
 
 def reader(metric: str, root: Path = ROOT):

@@ -21,8 +21,6 @@ limit the configuration file states:
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,23 +63,9 @@ def sample_streams(n_streams: int, n: int, seed: int) -> np.ndarray:
     return np.sort(rng.choice(n_streams, size=min(n, n_streams), replace=False))
 
 
-@functools.lru_cache(maxsize=8)
-def _reference_fn(ref, keep_frames, modes_items):
-    modes = dict(modes_items)
-
-    @jax.jit
-    def fn(params, windows):
-        feats = ref.features(windows, modes.get("front_end", "fp32"))
-        return ref.forward(params, feats, keep_frames, modes)[:, 1]
-
-    return fn
-
-
 def reference_p(cell, params, windows: np.ndarray, modes: dict) -> np.ndarray:
-    """Probability of "UAV" from the configuration's reference, in blocks."""
-    ref = cell.reference
-    pruned, keep_frames = ref.prune(params, cell.config["model"], cell.config["bake"].get("prune"))
-    fn = _reference_fn(ref, keep_frames, tuple(sorted(modes.items())))
+    """Probability of "UAV" from the configuration's reference (its
+    ``p_uav``), in blocks of rows at float32 ``highest``."""
     out = []
     for i in range(0, len(windows), BLOCK_ROWS):
         blk = windows[i : i + BLOCK_ROWS]
@@ -89,7 +73,8 @@ def reference_p(cell, params, windows: np.ndarray, modes: dict) -> np.ndarray:
         if n < BLOCK_ROWS:
             blk = np.concatenate([blk, np.repeat(blk[-1:], BLOCK_ROWS - n, axis=0)])
         with jax.default_matmul_precision("highest"):
-            out.append(np.asarray(fn(pruned, jnp.asarray(blk)))[:n])
+            p = cell.reference.p_uav(params, jnp.asarray(blk), cell.config, modes)
+            out.append(np.asarray(p)[:n])
     return np.concatenate(out).astype(np.float64) if out else np.zeros(0)
 
 

@@ -7,11 +7,13 @@ kernels, batching or caching.  It imports nothing of the program under test
 and takes nothing the program made: the harness hands it the same float
 weights it hands the program, and it prunes and quantises for itself.
 
-``features`` and ``forward`` also compute the benchmark's control: the same
-detector with every layer one precision step below what the configuration
-states (int8 -> int4, bf16 -> int8, fp32 -> bf16), as symmetric fake
-quantisation with per-row activation scales and per-output-channel weight
-scales; the float32 front-end's projections drop to bfloat16 operands.
+``p_uav`` is what the benchmark's check calls: the configuration's prune,
+then ``features`` and ``forward``, jitted.  They also compute its control:
+the same detector with every layer one precision step below what the
+configuration states (int8 -> int4, bf16 -> int8, fp32 -> bf16), as
+symmetric fake quantisation with per-row activation scales and
+per-output-channel weight scales; the float32 front-end's projections drop
+to bfloat16 operands.
 
 Departures from the paper, all shared with the program's deployment: random
 seeded weights stand in for trained ones; the feature vector is 20 MFCCs x
@@ -198,6 +200,34 @@ def forward(params: dict, feats: jax.Array, keep_frames: int | None, modes: dict
         if i < len(denses) - 1:
             h = jnp.maximum(h, 0.0)
     return jax.nn.softmax(h, axis=-1)
+
+
+@functools.lru_cache(maxsize=8)
+def _p_uav_fn(keep_frames: int | None, modes_items: tuple):
+    modes = dict(modes_items)
+
+    @jax.jit
+    def fn(params, windows):
+        feats = features(windows, modes.get("front_end", "fp32"))
+        return forward(params, feats, keep_frames, modes)[:, 1]
+
+    return fn
+
+
+#: the last call's (params, model, prune settings, pruned params, kept frames):
+#: the check calls ``p_uav`` once per block of rows with the same weights
+_last_prune: list = []
+
+
+def p_uav(params: dict, windows: jax.Array, config: dict, modes: dict) -> jax.Array:
+    """(B, 12800) raw windows -> (B,) probability of "UAV" of the network as
+    served: the configuration's prune, the MFCC-20 front-end and eq. 1, each
+    layer (and ``front_end``) in the precision ``modes`` gives it."""
+    key = (config["model"], config["bake"].get("prune"))
+    if not (_last_prune and _last_prune[0] is params and _last_prune[1:3] == list(key)):
+        _last_prune[:] = [params, *key, *prune(params, *key)]
+    pruned, keep_frames = _last_prune[3:]
+    return _p_uav_fn(keep_frames, tuple(sorted(modes.items())))(pruned, windows)
 
 
 def control_modes(stated: dict, lower=None) -> dict:

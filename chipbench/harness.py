@@ -1,9 +1,11 @@
 """One run of one cell: set up, measure, check, report.
 
-``run(cell, seed, seconds, trace)`` builds the detector engine of the cell's
-configuration from seeded float weights, drives it with the cell's traffic
-for ``seconds`` after a warm-up, checks what the timed path scored against
-the configuration's plain reference, and returns the result line.
+``run(cell, seed, seconds, trace)`` builds the program under test of the
+cell's configuration from seeded float weights, both through the
+configuration's family module (``chipbench/families/<family>.py``), drives
+it with the cell's traffic for ``seconds`` after a warm-up, checks what the
+timed path scored against the configuration's plain reference, and returns
+the result line.
 """
 from __future__ import annotations
 
@@ -15,7 +17,7 @@ import tempfile
 import jax
 import numpy as np
 
-from chipbench import catalog, check, flops, load, weights
+from chipbench import catalog, check, flops, load, scopes
 from chipbench import trace as tracemod
 
 #: longest stretch of a run the profiler records (seconds)
@@ -43,41 +45,6 @@ def percentile(values, q: float) -> float:
     return float(statistics.quantiles(values, n=100)[int(q) - 1])
 
 
-def build_engine(cell, params):
-    """The program under test, through its public engine constructor."""
-    from repro.core.precision_policy import PrecisionPolicy
-    from repro.core.pruning import plan_prune
-    from repro.models.cnn1d import CNNConfig
-    from repro.serving.engine import MonitorEngine
-
-    cfgj = cell.config
-    m = cfgj["model"]
-    cfg = CNNConfig(input_len=m["input_len"], channels=tuple(m["channels"]), kernel=m["kernel"],
-                    hidden=m["hidden"], n_classes=m["n_classes"])
-    bake = cfgj["bake"]
-    prune = policy = None
-    if bake.get("prune"):
-        last = f"conv{len(m['channels']) - 1}"
-        prune = plan_prune(params[last]["w"], cfg.n_frames, keep=bake["prune"]["keep"],
-                           trim_frames=bake["prune"]["trim_frames"])
-    if bake.get("policy"):
-        policy = PrecisionPolicy.parse(bake["policy"], default=bake["mode"])
-    eng = cfgj["engine"]
-    return MonitorEngine(
-        params, cfg,
-        n_streams=cell.traffic["streams"],
-        feature_kind=cfgj["feature_kind"],
-        on_device_features=True,
-        batch_slots=eng["batch_slots_per_chip"] * cell.chips,
-        precision=bake["mode"],
-        prune=prune,
-        policy=policy,
-        capacity_windows=eng["capacity_windows"],
-        shards=cell.chips if cell.chips > 1 else None,
-        **eng["tracker"],
-    )
-
-
 def memory_peak_bytes(devices) -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices]
     return int(max(peaks))
@@ -96,8 +63,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         cell = catalog.load_cell(cell, root)
     devices = devices if devices is not None else jax.devices()[: cell.chips]
     rng = np.random.default_rng([seed, 1])
-    params = weights.make(cell.config["model"], seed)
-    engine = build_engine(cell, params)
+    params = cell.family.weights(cell.config["model"], seed)
+    engine = cell.family.engine(cell, params)
     engine.precompile()
     spans, scores = load.Spans(), load.Scores()
     trace_len = min(TRACE_SECONDS, 0.3 * seconds) if trace else 0.0
@@ -137,19 +104,20 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     mem_peak = memory_peak_bytes(devices)
 
     sc = scores.arrays()
-    tr = None
+    tr = op_scopes = None
     if trace:
         tr = tracemod.load(profile_dir.name, keep_trace)
         profile_dir.cleanup()
+        op_scopes = scopes.of_engine(engine)
 
     device = jax.devices()[0]
     r = Readings(
         cell=cell, seed=seed, seconds=seconds, chips=cell.chips,
         t0=t0, t_end=t_end, t_last=t_last, t_untraced=t_untraced,
         setup_s=setup_s, spans=spans, scores=sc, latency_ms=res["latency_ms"],
-        lag_ms=res["lag_ms"], trace=tr, segment=seg,
+        lag_ms=res["lag_ms"], trace=tr, segment=seg, op_scopes=op_scopes,
         peak_s_per_window=flops.peak_seconds_per_window(
-            cell.config, catalog.peaks(device.device_kind, root)),
+            cell.family.layers(cell.config), catalog.peaks(device.device_kind, root)),
     )
 
     metrics = {}

@@ -5,11 +5,11 @@ The program runs each layer of its forward under a ``jax.named_scope``
 compiled operation names its layer in its ``op_name``.  A device operation
 of the trace is named by its HLO instruction (``%fusion.166 = f32[...]
 ...``); ``MonitorEngine.op_scopes()`` maps instruction names to scopes for
-the forward as the cell's engine compiles it.  The reader here rebuilds that
-engine from the cell and the run's seed (the compile cache makes this
-cheap), and sums, per scope, the union of its operations' intervals inside
-the traced segment, over chips.  A program without ``op_scopes`` yields
-nothing.
+the forward as the cell's engine compiles it.  The harness takes that map
+from the engine the run measured, once the traced window has closed
+(:func:`of_engine`), and the reader here sums, per scope, the union of its
+operations' intervals inside the traced segment, over chips.  A program
+without ``op_scopes`` yields nothing.
 """
 from __future__ import annotations
 
@@ -28,17 +28,10 @@ def instruction(event_name: str) -> str:
     return m.group(1) if m else event_name
 
 
-def program_scopes(r) -> dict[str, str] | None:
-    """``{instruction: scope}`` of the cell's forward, or None where the
+def of_engine(engine) -> dict[str, str] | None:
+    """``{instruction: scope}`` of the engine's forward, or None where the
     program has no ``op_scopes``."""
-    from repro.serving.engine import MonitorEngine
-
-    if not hasattr(MonitorEngine, "op_scopes"):
-        return None
-    from chipbench import harness, weights
-
-    engine = harness.build_engine(r.cell, weights.make(r.cell.config["model"], r.seed))
-    return engine.op_scopes()
+    return engine.op_scopes() if hasattr(engine, "op_scopes") else None
 
 
 def scope_seconds(trace: dict, scopes: dict[str, str]) -> dict[str, float]:
@@ -61,8 +54,7 @@ def layer_seconds(r) -> dict[str, float] | None:
     if r.trace is None or not r.trace["devices"]:
         return None
     if not hasattr(r, "_layer_seconds"):
-        scopes = program_scopes(r)
-        r._layer_seconds = None if scopes is None else scope_seconds(r.trace, scopes)
+        r._layer_seconds = None if r.op_scopes is None else scope_seconds(r.trace, r.op_scopes)
     return r._layer_seconds
 
 

@@ -13,7 +13,7 @@ import time
 import numpy as np
 import pytest
 
-from chipbench import harness
+from chipbench import catalog, flops, harness, scopes
 from chipbench.tests.conftest import ROOT
 
 SECONDS = 2.0
@@ -114,6 +114,123 @@ def test_new_loop_and_mix_are_found_by_name(tiny_root, tmp_path):
     assert out["correct"], out["checks"]
     assert set(out["metrics"]) == {"windows_per_s", "setup_s"}
     assert out["failed"] == 0 and out["attempted"] > 0 and out["attempted"] % 8 == 0
+
+
+ONE_CONV_FAMILY = '''
+"""A one-conv detector with uniform weights: a family the harness has never seen."""
+import jax
+import jax.numpy as jnp
+
+from chipbench.weights import key
+
+
+def weights(model, seed):
+    width, hidden = model["width"], model["hidden"]
+    flatten = model["input_len"] // 2 * width
+    shapes = {"conv0": (3, 1, width), "dense0": (flatten, hidden), "dense1": (hidden, 2)}
+
+    @jax.jit
+    def init(k):
+        ks = iter(jax.random.split(k, 2 * len(shapes)))
+        out = {}
+        for name, shape in shapes.items():
+            lim = (6.0 / (shape[0] * shape[1] if len(shape) == 3 else shape[0])) ** 0.5
+            out[name] = {"w": jax.random.uniform(next(ks), shape, jnp.float32, -lim, lim),
+                         "b": jax.random.uniform(next(ks), shape[-1:], jnp.float32, -0.1, 0.1)}
+        return out
+
+    return init(key(seed))
+
+
+def engine(cell, params):
+    from repro.models.cnn1d import CNNConfig
+    from repro.serving.engine import MonitorEngine
+
+    m, eng = cell.config["model"], cell.config["engine"]
+    cfg = CNNConfig(input_len=m["input_len"], channels=(m["width"],), kernel=3,
+                    hidden=m["hidden"], n_classes=2)
+    return MonitorEngine(params, cfg, n_streams=cell.traffic["streams"], feature_kind="mfcc20",
+                         on_device_features=True, batch_slots=eng["batch_slots_per_chip"],
+                         precision="int8", capacity_windows=eng["capacity_windows"],
+                         **eng["tracker"])
+
+
+def layers(config):
+    m = config["model"]
+    n, w, h = m["input_len"], m["width"], m["hidden"]
+    return [("conv0", 2 * n * 3 * w, "int8"), ("dense0", 2 * (n // 2) * w * h, "int8"),
+            ("dense1", 2 * h * 2, "int8")]
+'''
+
+ONE_CONV_REFERENCE = '''
+"""Reference of the one-conv detector: the 1D-CNN reference's layers, no prune."""
+from pathlib import Path
+
+from chipbench import catalog
+
+_cnn = catalog._module(Path(__file__).with_name("shield8_cnn_reference.py"))
+control_modes, track = _cnn.control_modes, _cnn.track
+
+
+def p_uav(params, windows, config, modes):
+    feats = _cnn.features(windows, modes.get("front_end", "fp32"))
+    return _cnn.forward(params, feats, None, modes)[:, 1]
+'''
+
+
+def test_new_family_is_found_by_name(tiny_root, tmp_path):
+    """A model family, its reference, a configuration and a cell added as
+    new files plus entries in ``BENCHMARK.json`` run through the harness
+    unchanged: weights, engine and operation counts come from the family."""
+    root = tmp_path / "co"
+    shutil.copytree(tiny_root, root)
+    here = root / "chipbench"
+    (here / "families" / "one_conv.py").write_text(ONE_CONV_FAMILY)
+    (here / "configs" / "one_conv_reference.py").write_text(ONE_CONV_REFERENCE)
+    tiny = json.loads((here / "configs" / "tiny.json").read_text())
+    stated = {"front_end": "fp32", "conv0": "int8", "dense0": "int8", "dense1": "int8"}
+    (here / "configs" / "one_conv.json").write_text(json.dumps(dict(
+        tiny, name="one_conv", family="one_conv", reference="one_conv_reference",
+        model={"input_len": 1096, "width": 4, "hidden": 8}, stated_precision=stated)))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "one_conv", "source": "test",
+                             "file": "chipbench/configs/one_conv.json", "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "one_conv.catchup", "config": "one_conv",
+                               "traffic": "tiny_catchup", "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "int8.catchup" in m.get("workloads", []):
+            m["workloads"].append("one_conv.catchup")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = catalog.load_cell("one_conv.catchup", root)
+    assert flops.ops_per_window(cell.family.layers(cell.config)) == 2 * 1096 * 3 * 4 + 2 * 548 * 4 * 8 + 32
+    assert set(cell.family.weights(cell.config["model"], 3)) == {"conv0", "dense0", "dense1"}
+    out = _run(root, "one_conv.catchup")
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"windows_per_s", "setup_s"}
+    assert out["failed"] == 0 and out["attempted"] > 0 and out["windows_compared"] > 0
+    out = _run(root, "one_conv.catchup", trace=True)
+    assert out["correct"], out["checks"]
+    assert {"push_us_per_window", "step_us_per_window", "mfu_pct"} <= set(out["metrics"])
+
+
+def test_traced_run_builds_one_engine(tiny_root, monkeypatch):
+    """The op-to-layer map of a traced run comes from the engine the run
+    measured: no second engine, and no second copy of the weights."""
+    from repro.serving import engine
+
+    built = []
+    orig = engine.MonitorEngine.__init__
+
+    def init(self, *a, **kw):
+        built.append(self)
+        orig(self, *a, **kw)
+
+    monkeypatch.setattr(engine.MonitorEngine, "__init__", init)
+    seen = []
+    monkeypatch.setattr(scopes, "of_engine", lambda e: seen.append(e) or {})
+    out = _run(tiny_root, "tiny.catchup", trace=True)
+    assert out["correct"], out["checks"]
+    assert len(built) == 1 and seen == built
 
 
 def _alter_answer(monkeypatch):

@@ -37,22 +37,26 @@ def test_scope_seconds_are_unions_per_scope_summed_over_chips():
     assert got[""] == pytest.approx(500e-9)
 
 
-def _readings(cell, trace, windows):
+def _readings(cell, trace, windows, op_scopes=None):
     spans = load.Spans()
     spans.name += ["step", "step"]
     spans.start += [2e-6, 20e-6]  # the second round starts after the segment
     spans.end += [3e-6, 21e-6]
     spans.windows += [windows, 99]
     return harness.Readings(cell=cell, seed=2**31 + 5, trace=trace, spans=spans,
-                            segment=(1e-6, 11e-6))
+                            segment=(1e-6, 11e-6), op_scopes=op_scopes)
+
+
+def _engine(cell):
+    return cell.family.engine(cell, cell.family.weights(cell.config["model"], 2**31 + 5))
 
 
 def test_device_scope_readers_on_a_tiny_cell(tiny_root):
     """The readers take the op-to-layer map from the cell's own engine."""
     cell = catalog.load_cell("tiny.catchup", tiny_root)
-    probe = _readings(cell, None, 4)
+    op_scopes = scopes.of_engine(_engine(cell))
     by_scope: dict[str, str] = {}
-    for inst, scope in scopes.program_scopes(probe).items():
+    for inst, scope in op_scopes.items():
         by_scope.setdefault(scope, inst)
     assert set(by_scope) == {"frontend", "conv0", "conv1", "dense0", "dense1", "softmax"}
     length = {"frontend": 4_000, "conv0": 1_000, "conv1": 1_000, "dense0": 800,
@@ -61,7 +65,7 @@ def test_device_scope_readers_on_a_tiny_cell(tiny_root):
     for scope, ns in length.items():
         dev.append((f"%{by_scope[scope]} = f32[8] op()", t, t + ns))
         t += ns
-    r = _readings(cell, {"devices": {0: dev}, "host": [WIN]}, 4)
+    r = _readings(cell, {"devices": {0: dev}, "host": [WIN]}, 4, op_scopes)
     per_window = {name: catalog.reader(name, tiny_root)(r)
                   for name in ("frontend_device_us_per_window", "conv_device_us_per_window",
                                "dense_device_us_per_window")}
@@ -80,8 +84,10 @@ def test_device_scope_readers_find_nothing_to_read(tiny_root, monkeypatch):
     assert read(_readings(cell, None, 4)) is None
     assert read(_readings(cell, {"devices": {}, "host": [WIN]}, 4)) is None
     monkeypatch.delattr(MonitorEngine, "op_scopes")
+    op_scopes = scopes.of_engine(_engine(cell))
+    assert op_scopes is None
     dev = {0: [("%fusion.1 = f32[1] fusion()", 2_000, 3_000)]}
-    assert read(_readings(cell, {"devices": dev, "host": [WIN]}, 4)) is None
+    assert read(_readings(cell, {"devices": dev, "host": [WIN]}, 4, op_scopes)) is None
 
 
 def test_recorded_v5e_trace_names_ops_by_instruction_and_layer():

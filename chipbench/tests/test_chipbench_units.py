@@ -1,6 +1,7 @@
 """CPU tests of the benchmark's pieces that need no engine run."""
 from __future__ import annotations
 
+import hashlib
 import json
 import types
 
@@ -8,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import catalog, check, flops, load, trace, weights
+from chipbench import catalog, check, flops, load, trace
 from chipbench.scenes import SR, WINDOW, ScenePool
 from chipbench.tests.conftest import ROOT
 
@@ -17,18 +18,26 @@ def _config(name):
     return json.loads((ROOT / "chipbench" / "configs" / f"{name}.json").read_text())
 
 
+def _weights(cfg, seed):
+    return catalog.family(cfg["family"]).weights(cfg["model"], seed)
+
+
+def _layers(cfg):
+    return catalog.family(cfg["family"]).layers(cfg)
+
+
 @pytest.mark.parametrize("name,ops", [("shield8_int8", 85_716_224),
                                       ("shield8_pruned_mixed", 41_938_176)])
 def test_ops_per_window(name, ops):
-    assert flops.ops_per_window(_config(name)) == ops
+    assert flops.ops_per_window(_layers(_config(name))) == ops
 
 
 def test_peak_seconds_use_each_layers_precision():
     peaks = catalog.peaks("TPU v5 lite")
     cfg = _config("shield8_pruned_mixed")
     want = sum(n / (197e12 if mode in ("bf16", "fp32") else 393e12)
-               for _, n, mode in flops.layers(cfg))
-    assert flops.peak_seconds_per_window(cfg, peaks) == pytest.approx(want)
+               for _, n, mode in _layers(cfg))
+    assert flops.peak_seconds_per_window(_layers(cfg), peaks) == pytest.approx(want)
     assert peaks["int8_ops_per_s"] == 393e12 and peaks["hbm_bytes_per_s"] == 819e9
 
 
@@ -202,7 +211,7 @@ def test_control_fails_the_limit_at_published_width(name):
     cfg = _config(name)
     cell = types.SimpleNamespace(config=cfg, reference=catalog._module(
         ROOT / "chipbench" / "configs" / f"{cfg['reference']}.py"))
-    params = weights.make(cfg["model"], 2**31 + 99)
+    params = _weights(cfg, 2**31 + 99)
     pool = ScenePool(48, 6, 4, 30_000, np.random.default_rng(99))
     x = np.concatenate([pool.windows(s, 0, 2) for s in range(48)])
     stated = cfg["stated_precision"]
@@ -239,9 +248,125 @@ def test_reference_tracker_float32_control_departs():
 def test_reference_prune_matches_table_one():
     cfg = _config("shield8_pruned_mixed")
     ref = catalog._module(ROOT / "chipbench" / "configs" / "shield8_cnn_reference.py")
-    params = weights.make(cfg["model"], 5)
+    params = _weights(cfg, 5)
     pruned, kf = ref.prune(params, cfg["model"], cfg["bake"]["prune"])
     assert kf == 136 and pruned["dense0"]["w"].shape == (8_704, 64)
     imp = jnp.abs(params["conv2"]["w"]).sum(axis=(0, 1))
     kept = np.sort(np.argsort(np.asarray(imp))[-64:])
     assert np.array_equal(np.asarray(pruned["conv2"]["w"]), np.asarray(params["conv2"]["w"])[:, :, kept])
+
+
+# -- the family seam: the 1D-CNN's weights, reference and checks as before it --
+
+PIN_SEED = 2**31 + 77
+#: sha256 of the float weights of both configurations' model at PIN_SEED, as
+#: the harness made them before the family seam (``weights.make``)
+PIN_WEIGHTS = "7656e18674232370ff04287dd0201464f1af83aa7386b93458014cc946b162cf"
+#: the reference's probability of "UAV" on ``_pin_windows()`` at PIN_SEED, as
+#: ``check.reference_p`` composed it before the seam (prune, features, forward,
+#: column 1); the last bits move with the CPU's thread count
+PIN_P = {
+    "shield8_int8": [0.860665500164032, 0.7515239715576172, 0.8030366897583008, 0.631642758846283,
+                     0.7870256900787354, 0.4299183487892151, 0.46452897787094116, 0.7688567042350769,
+                     0.5157069563865662, 0.8295890092849731, 0.6944171190261841, 0.373270720243454,
+                     0.4311385452747345, 0.6338753700256348, 0.5469149351119995, 0.8514454960823059],
+    "shield8_pruned_mixed": [0.12751910090446472, 0.13443829119205475, 0.09411362558603287,
+                             0.07955709844827652, 0.10896006971597672, 0.2374526709318161,
+                             0.14062075316905975, 0.07738539576530457, 0.13482354581356049,
+                             0.06303030997514725, 0.11038424074649811, 0.21716490387916565,
+                             0.10404393821954727, 0.07027792930603027, 0.1300031840801239,
+                             0.09292779862880707],
+}
+#: ``check.compare`` on ``_pin_scores`` at PIN_SEED, before the seam
+PIN_CHECKS = {
+    "shield8_int8": {"logit_gap": 1.691331284424362, "tracker_gap": 1.3999999992631018e-08,
+                     "unscored": 1.0},
+    "shield8_pruned_mixed": {"logit_gap": 2.774721914099131, "tracker_gap": 1.3999999992631018e-08,
+                             "unscored": 1.0},
+}
+PIN_OPS = {"shield8_int8": 85_716_224, "shield8_pruned_mixed": 41_938_176}
+
+
+def _digest(params) -> str:
+    h = hashlib.sha256()
+    for layer in sorted(params):
+        for k in sorted(params[layer]):
+            a = np.asarray(params[layer][k])
+            h.update(f"{layer}/{k}{a.shape}{a.dtype}".encode())
+            h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def _pin_windows():
+    pool = ScenePool(8, 2, 2, WINDOW, np.random.default_rng(7))
+    return np.concatenate([pool.windows(s, 0, 2) for s in range(8)])
+
+
+def _reference_cell(cfg, **kw):
+    return types.SimpleNamespace(config=cfg, reference=catalog._module(
+        ROOT / "chipbench" / "configs" / f"{cfg['reference']}.py"), **kw)
+
+
+@pytest.mark.parametrize("name", sorted(PIN_P))
+def test_shield8_cnn_weights_are_pinned(name):
+    cfg = _config(name)
+    assert cfg["family"] == "shield8_cnn"
+    assert _digest(_weights(cfg, PIN_SEED)) == PIN_WEIGHTS
+
+
+@pytest.mark.parametrize("name", sorted(PIN_P))
+def test_p_uav_is_the_composition_it_replaced(name):
+    """``p_uav`` gives, bit for bit, what prune -> features -> forward ->
+    column 1 gives, for the reference and for its control, and the pinned
+    reference probabilities."""
+    import jax
+
+    cfg = _config(name)
+    ref = _reference_cell(cfg).reference
+    params = _weights(cfg, PIN_SEED)
+    x = jnp.asarray(_pin_windows())
+    pruned, keep_frames = ref.prune(params, cfg["model"], cfg["bake"].get("prune"))
+    stated = cfg["stated_precision"]
+    for modes in ({k: "fp32" for k in stated}, ref.control_modes(stated)):
+        @jax.jit
+        def composed(params, windows, modes=modes):
+            feats = ref.features(windows, modes.get("front_end", "fp32"))
+            return ref.forward(params, feats, keep_frames, modes)[:, 1]
+
+        with jax.default_matmul_precision("highest"):
+            got = np.asarray(ref.p_uav(params, x, cfg, modes))
+            want = np.asarray(composed(pruned, x))
+        assert np.array_equal(got, want)
+    cell = _reference_cell(cfg)
+    p = check.reference_p(cell, params, _pin_windows(), {k: "fp32" for k in stated})
+    np.testing.assert_allclose(p, PIN_P[name], rtol=1e-5)
+
+
+def _pin_scores(ref):
+    """Six streams answered three windows each, in order, but for stream 5's
+    last; the smoothed scores a little off the reference tracker's."""
+    stream = np.repeat(np.arange(6), 3)[:-1]
+    idx = np.tile(np.arange(3), 6)[:-1]
+    p = np.random.default_rng(12).uniform(0.05, 0.95, len(stream))
+    sm, act = np.empty_like(p), np.empty(len(p), bool)
+    for s in range(6):
+        sel = stream == s
+        sm[sel], act[sel] = ref.track(p[sel], ema_alpha=0.4, enter=0.65, exit=0.35)
+    sm += 1e-9 * np.arange(len(sm))
+    return {"stream": stream, "idx": idx, "p": p, "smoothed": sm, "active": act}
+
+
+@pytest.mark.parametrize("name", sorted(PIN_CHECKS))
+def test_checks_and_ops_are_pinned(name):
+    """The numbers that decide ``correct``, and the operation count, read
+    what they read before the family seam."""
+    cfg = _config(name)
+    cell = _reference_cell(cfg, traffic={"check_streams": 3, "check_windows": 8})
+    pool = ScenePool(6, 2, 3, WINDOW, np.random.default_rng(11))
+    checks, n = check.compare(cell, _weights(cfg, PIN_SEED), pool, _pin_scores(cell.reference),
+                              np.full(6, 3 * WINDOW + 5), PIN_SEED)
+    assert n == 17
+    got = {k: v["value"] for k, v in checks.items()}
+    assert got == pytest.approx(PIN_CHECKS[name], rel=1e-5)
+    assert got["tracker_gap"] == PIN_CHECKS[name]["tracker_gap"]
+    assert flops.ops_per_window(_layers(cfg)) == PIN_OPS[name]
