@@ -9,7 +9,9 @@ standard identities (Parseval, DC response, filterbank partition-of-unity).
 Every feature set yields a fixed-length 1-D vector (the 1D-F-CNN consumes
 ``x ∈ R^{1×M}``); lengths are chosen so the canonical deployed model (MFCC-20)
 reproduces the paper's flatten size exactly: M=1096 → 3 pools → 137 frames ×
-256 ch = 35,072 (Table I).
+256 ch = 35,072 (Table I).  The ``waveform`` kind is the raw window itself,
+zero-mean and unit-variance, as the HuBERT verifier (``models/hubert.py``)
+reads it.
 """
 from __future__ import annotations
 
@@ -29,7 +31,12 @@ FEATURE_DIMS = {
     "mel128": 1024,  # 128 mel bands x 8 pooled time segments
     "psd": 512,  # 512-bin log10 Welch PSD
     "zcr": 128,  # 128-frame ZCR sequence
+    "waveform": N_SAMPLES,  # the raw window, zero-mean and unit-variance
 }
+
+#: variance floor of the ``waveform`` kind's normalisation (the HuBERT
+#: feature extractor's ``do_normalize``)
+WAVEFORM_EPS = 1e-7
 
 
 def frame_signal(x: np.ndarray, n_fft: int = N_FFT, hop: int = HOP) -> np.ndarray:
@@ -131,6 +138,9 @@ def _normalize(v: np.ndarray) -> np.ndarray:
 def feature_vector(x: np.ndarray, kind: str = "mfcc20") -> np.ndarray:
     """Extract the 1×M feature vector for one 0.8 s window."""
     x = np.asarray(x, np.float64)
+    if kind == "waveform":
+        x = x - x.mean()
+        return (x / np.sqrt(np.mean(x**2) + WAVEFORM_EPS)).astype(np.float32)
     peak = np.max(np.abs(x)) + 1e-9
     x = x / peak  # amplitude normalisation of the raw window
     if kind == "mfcc20":

@@ -45,6 +45,7 @@ from repro.data.features import (
     HOP,
     N_FFT,
     N_SAMPLES,
+    WAVEFORM_EPS,
     dct_ii,
     mel_filterbank,
 )
@@ -60,6 +61,7 @@ PARITY_ATOL = {
     "mel128": 5e-3,
     "psd": 5e-3,
     "zcr": 1e-4,
+    "waveform": 1e-4,
 }
 
 
@@ -179,6 +181,9 @@ def _feature_batch(x: jax.Array, kind: str) -> jax.Array:
     Mirrors :func:`repro.data.features.feature_vector` op for op, in float32.
     """
     bsz = x.shape[0]
+    if kind == "waveform":
+        x = x - _pairwise_mean(x)[:, None]
+        return x / jnp.sqrt(_pairwise_mean(x**2) + WAVEFORM_EPS)[:, None]
     peak = jnp.max(jnp.abs(x), axis=1, keepdims=True) + 1e-9
     x = x / peak
     if kind == "mfcc20":

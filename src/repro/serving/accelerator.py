@@ -1,6 +1,14 @@
-"""Deployed-datapath inference: the whole 1D-F-CNN through the Pallas kernels.
+"""Deployed-datapath inference: the served forward of each model kind.
 
-This is the software twin of the POLARON accelerator's execution: every
+Two kinds are served, picked by the config's type: the paper's 1D-F-CNN
+(:class:`~repro.models.cnn1d.CNNConfig`, below) and the HuBERT X-Large
+second-stage verifier (:class:`~repro.models.hubert.HubertConfig`, baked to
+a :class:`~repro.models.hubert.HubertParams` artifact and run by
+:func:`_forward_verifier`).  Both take the same (B, 12800) raw-window blocks
+from the engine, pad a short batch to ``MIN_ROWS`` and run each layer under
+a named scope (:data:`SCOPES`).
+
+The CNN's forward is the software twin of the POLARON accelerator's execution: every
 convolution and dense layer runs on the W8A8 kernels — conv on the fused
 in-kernel-im2col conv kernel, dense on quant_matmul — with bias+ReLU fused
 into each layer's dequant epilogue, and the classifier head finishes with
@@ -39,15 +47,22 @@ from repro.data import features_jax
 from repro.distributed.sharding import STREAM_AXIS
 from repro.kernels import ops
 from repro.kernels.backend import resolve_interpret
+from repro.models import hubert
 from repro.models.cnn1d import CNNConfig, _maxpool2
+from repro.models.hubert import HubertConfig, HubertParams
 from repro.serving.quantized_params import QuantizedParams, quantize_params
 
 
 #: fewest rows a forward computes (one f32 sublane tile); see _forward_quantized
 MIN_ROWS = 8
 
-#: the named scopes of the forward's layers, as they appear in an op_name
-SCOPES = re.compile(r"^(frontend|conv\d+|flatten|dense\d+|softmax)$")
+#: the named scopes of the forwards' layers, as they appear in an op_name:
+#: the CNN's (frontend, conv<i>, flatten, dense<i>, softmax) and the
+#: verifier's (frontend, waveform, featproj, posconv, attn, ffn, head)
+SCOPES = re.compile(
+    r"^(frontend|conv\d+|flatten|dense\d+|softmax"
+    r"|waveform|featproj|posconv|attn|ffn|head)$"
+)
 
 
 def _quantizer(layer_mode: str):
@@ -170,6 +185,17 @@ def _dense_layer(h, layer, lmode, act, act_axis, per_sample_acts, interpret):
     return jnp.maximum(h, 0.0) if act == "relu" else h
 
 
+@functools.partial(jax.jit, static_argnames=("raw_windows",))
+def _forward_verifier(art: HubertParams, x: jax.Array, raw_windows: bool = False) -> jax.Array:
+    """The HuBERT verifier's served program (:func:`repro.models.hubert.forward`),
+    with a batch below ``MIN_ROWS`` padded as the CNN's is."""
+    n_rows = x.shape[0]
+    if 0 < n_rows < MIN_ROWS:
+        with jax.named_scope("frontend"):
+            x = jnp.pad(x, ((0, MIN_ROWS - n_rows), (0, 0)), mode="edge")
+    return hubert.forward(art, x, raw_windows)[:n_rows]
+
+
 def _check_raw_windows(qp: QuantizedParams, x: jax.Array, feature_kind: str | None):
     """Validate the raw-window contract before tracing (clear errors beat
     shape mismatches inside jit)."""
@@ -192,9 +218,9 @@ def _check_raw_windows(qp: QuantizedParams, x: jax.Array, feature_kind: str | No
 
 
 def accelerator_forward(
-    params: dict | QuantizedParams,
+    params: dict | QuantizedParams | HubertParams,
     x: jax.Array,
-    cfg: CNNConfig,
+    cfg: CNNConfig | HubertConfig,
     *,
     fxp: bool = False,
     interpret: bool | None = None,
@@ -204,6 +230,11 @@ def accelerator_forward(
 ) -> jax.Array:
     """x: (B, M) features -> (B, n_classes) class probabilities, computed
     entirely on the kernel datapath.
+
+    With a :class:`~repro.models.hubert.HubertConfig` the HuBERT verifier
+    serves instead: ``params`` is its baked ``HubertParams`` (or the float
+    checkpoint, baked here), ``x`` holds (B, 12800) windows, raw or already
+    normalised, and the quantisation options do not apply.
 
     Pass a :class:`QuantizedParams` artifact to serve from the weight cache
     (zero weight-quantisation work per call) — pruned and mixed-precision
@@ -223,6 +254,13 @@ def accelerator_forward(
     batch row; ``False`` restores the legacy per-tensor scale (kept as the
     A/B surface for the mixed-loudness regression tests).
     """
+    if isinstance(cfg, HubertConfig):
+        if x.ndim != 2 or x.shape[1] != cfg.input_len:
+            raise ValueError(
+                f"the verifier takes (B, {cfg.input_len}) windows, got {tuple(x.shape)}"
+            )
+        art = params if isinstance(params, HubertParams) else hubert.bake(params, cfg)
+        return _forward_verifier(art, x, raw_windows)
     if isinstance(params, QuantizedParams):
         qp = params
     else:
@@ -326,8 +364,8 @@ def accelerator_forward_sharded(
 
 
 def precompile_slot_shapes(
-    qp: QuantizedParams,
-    cfg: CNNConfig,
+    qp: QuantizedParams | HubertParams,
+    cfg: CNNConfig | HubertConfig,
     slot_counts,
     *,
     row_width: int | None = None,
@@ -347,9 +385,9 @@ def precompile_slot_shapes(
     program is built.  Per-sample activation scales make the traced numbers
     irrelevant — only the shapes enter the cache key.
     """
-    if not isinstance(qp, QuantizedParams):
+    if not isinstance(qp, (QuantizedParams, HubertParams)):
         raise TypeError(
-            f"precompile_slot_shapes needs a baked QuantizedParams artifact, "
+            f"precompile_slot_shapes needs a baked QuantizedParams or HubertParams artifact, "
             f"got {type(qp).__name__}"
         )
     if row_width is None:
@@ -424,8 +462,8 @@ def hlo_scopes(hlo_text: str) -> dict[str, str]:
 
 
 def forward_scopes(
-    qp: QuantizedParams,
-    cfg: CNNConfig,
+    qp: QuantizedParams | HubertParams,
+    cfg: CNNConfig | HubertConfig,
     slot_counts,
     *,
     row_width: int | None = None,
@@ -436,14 +474,17 @@ def forward_scopes(
 ) -> dict[str, str]:
     """:func:`hlo_scopes` of the forward compiled at each batch (slot)
     shape, as :func:`precompile_slot_shapes` builds it: the map from a
-    device trace's operation names to the forward's layers."""
+    device trace's operation names to the forward's layers (the CNN's or,
+    for a ``HubertParams`` artifact, the verifier's)."""
     if row_width is None:
         row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
     interp = resolve_interpret(interpret)
     out: dict[str, str] = {}
     for slots in sorted(set(int(s) for s in slot_counts)):
         x = jax.ShapeDtypeStruct((slots, row_width), jnp.float32)
-        if mesh is not None:
+        if isinstance(qp, HubertParams):
+            lowered = _forward_verifier.lower(qp, x, raw_windows)
+        elif mesh is not None:
             axis = STREAM_AXIS if axis_name is None else axis_name
             lowered = _forward_sharded.lower(qp, x, mesh, axis, interp, True, raw_windows)
         else:

@@ -10,8 +10,9 @@ This module scales that loop to N concurrent streams:
   in arbitrary chunk sizes and emit hop-aligned 0.8 s windows;
 * **continuous micro-batching** packs each round's ready windows into slot
   blocks of one jitted :func:`~repro.serving.accelerator.accelerator_forward`
-  program via the shared :class:`~repro.serving.batching.DispatchCore` (the
-  same core ``launch/serve.py``'s ``BatchedServer`` runs on): fixed
+  program (the detector's, or the HuBERT verifier's for a second stage) via
+  the shared :class:`~repro.serving.batching.DispatchCore` (the same core
+  ``launch/serve.py``'s ``BatchedServer`` runs on): fixed
   ``batch_slots`` blocks with silence-padded dead slots by default, or —
   with ``adaptive_slots=True`` — blocks grown/shrunk over a small
   pre-jittable ladder to fit the backlog, so one live stream dispatches a
@@ -48,7 +49,9 @@ import numpy as np
 from repro.data import features
 from repro.distributed.sharding import stream_mesh
 from repro.kernels.backend import resolve_interpret
+from repro.models import hubert
 from repro.models.cnn1d import CNNConfig
+from repro.models.hubert import HubertConfig, HubertParams
 from repro.serving.accelerator import (
     accelerator_forward,
     accelerator_forward_sharded,
@@ -319,7 +322,16 @@ class WindowScore:
 
 
 class MonitorEngine:
-    """N-stream continuous monitor over the quantised accelerator datapath.
+    """N-stream continuous monitor over the served accelerator datapath.
+
+    It serves either model kind of :mod:`repro.serving.accelerator`: the
+    1D-F-CNN detector (a :class:`~repro.models.cnn1d.CNNConfig`, baked to a
+    quantised artifact) or the HuBERT X-Large verifier (a
+    :class:`~repro.models.hubert.HubertConfig` with ``feature_kind=
+    "waveform"`` and ``precision="bf16"``, baked to a ``HubertParams``
+    artifact).  Rings, blocks, dispatch, tracker and telemetry are the same
+    for both; the verifier takes no ``prune``, ``policy``, ``shards`` or
+    ``mesh``.
 
     ``push`` raw audio per stream in any chunking; each ``step`` scores at
     most one ready window per stream (one *round*), micro-batched through
@@ -348,8 +360,8 @@ class MonitorEngine:
 
     def __init__(
         self,
-        params: dict | QuantizedParams,
-        cfg: CNNConfig,
+        params: dict | QuantizedParams | HubertParams,
+        cfg: CNNConfig | HubertConfig,
         *,
         n_streams: int,
         feature_kind: str = "mfcc20",
@@ -397,7 +409,19 @@ class MonitorEngine:
         # checkpoint with the deployment decisions (default precision, prune
         # spec, per-layer policy, fused front-end) applied at quantise-once
         # time.
-        if isinstance(params, QuantizedParams):
+        if isinstance(cfg, HubertConfig):
+            if precision != "bf16" or prune is not None or policy is not None:
+                raise ValueError(
+                    "the HuBERT verifier is served as one bf16 bake: pass "
+                    "precision='bf16' and no prune or policy"
+                )
+            if shards is not None or mesh is not None:
+                raise ValueError(
+                    "the HuBERT verifier is served on one device: pass no "
+                    "shards or mesh"
+                )
+            self._qp = params if isinstance(params, HubertParams) else hubert.bake(params, cfg)
+        elif isinstance(params, QuantizedParams):
             if prune is not None or policy is not None:
                 raise ValueError(
                     "prune/policy are quantise-once decisions and cannot be "
@@ -712,9 +736,11 @@ class MonitorEngine:
 
     def op_scopes(self) -> dict[str, str]:
         """``{HLO instruction name: layer scope}`` of the forward compiled at
-        every dispatchable slot shape (``frontend``, ``conv<i>``,
-        ``flatten``, ``dense<i>``, ``softmax``): what maps the operations of
-        a device trace to the layers of the model."""
+        every dispatchable slot shape (the CNN's ``frontend``, ``conv<i>``,
+        ``flatten``, ``dense<i>``, ``softmax``; the verifier's ``frontend``,
+        ``waveform``, ``featproj``, ``posconv``, ``attn``, ``ffn``,
+        ``head``): what maps the operations of a device trace to the layers
+        of the model."""
         return forward_scopes(
             self._qp,
             self.cfg,
