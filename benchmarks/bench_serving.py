@@ -90,7 +90,7 @@ BURSTY_ROUND_BUDGET = 8 * BATCH_SLOTS
 # Concurrent-fleet rows: the same fleet supervisor stepped sequentially vs
 # with per-worker execution lanes (threads).  Lanes overlap one worker's
 # host feature extraction with another's device scoring through the
-# dispatch core's in-flight rotation; results stay bitwise identical
+# dispatch core's queued blocks; results stay bitwise identical
 # (pinned by tests/test_lane_fleet.py), so the lane row is a pure
 # wall-clock measurement.  Target: >=1.3x aggregate windows/s at 4 workers
 # on a multi-core host.  The ratio is physically bounded by the host's

@@ -77,12 +77,11 @@ class BatchedServer:
         )
         self._greedy = True  # per-serve() decode mode, read by _submit
         # Synchronous program (prefill+decode completes before the next
-        # block is packed), so no harvest stage and a single in-flight slot.
+        # block is packed), so no harvest stage.
         self._core = DispatchCore(
             submit=self._submit,
             harvest=None,
             slot_policy=SlotPolicy(batch_slots, adaptive=adaptive_slots),
-            inflight=1,
         )
 
     @property

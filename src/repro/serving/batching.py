@@ -3,9 +3,9 @@
 The paper's datapath wins by keeping *one* sequential engine saturated
 instead of replicating hardware; the serving layer follows the same shape:
 work items (ready acoustic windows, queued LM requests) are packed into
-slot-blocks of a compiled program and rotated through a bounded in-flight
-pipeline.  Before this module, the detector fleet
-(:class:`repro.serving.engine.MonitorEngine`) and the LM side
+slot-blocks of a compiled program, and every block of a round is queued on
+the device before the first is harvested.  Before this module, the
+detector fleet (:class:`repro.serving.engine.MonitorEngine`) and the LM side
 (:class:`repro.launch.serve.BatchedServer`) each carried a private half-copy
 of that machinery; both now run on :class:`DispatchCore`.
 
@@ -21,25 +21,27 @@ The pieces, bottom up:
   jitted forward compiles a bounded set of batch shapes instead of
   retracing per backlog size; every ladder value is a multiple of
   ``multiple`` so sharded dispatch keeps dividing evenly.
-* :class:`BlockPool` — preallocated ``(slots, width)`` dispatch buffers,
-  one rotation of ``inflight + 1`` buffers per slot shape.  ``device_put``
-  on CPU may alias host memory zero-copy, so a buffer must never be
-  rewritten while its dispatch is still in flight; rotating ``inflight +
-  1`` deep guarantees the buffer being packed is older than every
-  unharvested submission (the invariant PR 5 pinned, now held in one
-  place for all slot shapes).
+* :class:`BlockPool` — preallocated ``(slots, width)`` dispatch buffers
+  per slot shape, one for each block of a round.  ``device_put`` on CPU
+  may alias host memory zero-copy, so a buffer must never be rewritten
+  while a block that reads it is in flight: each block owns its buffer
+  until it is harvested, and the pool hands buffers out again only after
+  :meth:`BlockPool.rewind`, once the whole round has come back.
 * :class:`DispatchCore` — the ready-work queue and the dispatch loop:
-  split items into slot-blocks via the policy, ``submit`` each block
-  (async handles welcome), harvest with at most ``inflight`` blocks
-  outstanding, and reassemble per-item results *in submission order*.
+  split items into slot-blocks via the policy, ``submit`` every block
+  (async handles welcome) before harvesting the first, and reassemble
+  per-item results *in submission order*.  The dispatch depth is the
+  round's own block count, so the device never waits for the host to
+  pack, put and launch a block of the round it is working through.
   ``dispatch`` is all-or-nothing: either every item's result is returned
-  (commit) or the exception propagates and the optional rollback hook
-  fires with no partial results observable — the transactional-round
-  protocol the monitor engine and the fleet supervisor's crash recovery
-  are built on.  ``pre_dispatch`` is the fault-injection seam
-  (:mod:`repro.serving.faults`): called with the items before anything is
-  submitted, it may raise (simulated crash) or stall, and the rollback
-  guarantee makes the failed round re-runnable.
+  (commit), or every block already submitted is waited on, the optional
+  rollback hook fires and the exception propagates with no partial
+  results observable — the transactional-round protocol the monitor
+  engine and the fleet supervisor's crash recovery are built on.
+  ``pre_dispatch`` is the fault-injection seam (:mod:`repro.serving.faults`):
+  called with the items before anything is submitted, it may raise
+  (simulated crash) or stall, and the rollback guarantee makes the failed
+  round re-runnable.
 * :class:`AdmissionPolicy` / :func:`fair_allocation` — fleet-scale stream
   admission and per-tenant fairness on top of the core: cap how many
   ready windows one stream may drain per round, bound the total round
@@ -137,50 +139,58 @@ class SlotPolicy:
 
 
 class BlockPool:
-    """Preallocated dispatch buffers: ``inflight + 1`` rotating ``(slots,
-    width)`` float32 blocks per slot shape, allocated lazily per shape.
+    """Preallocated ``(slots, width)`` dispatch buffers, allocated lazily
+    per slot shape, each owned by one in-flight block until it is harvested.
 
-    The rotation depth is the aliasing-safety invariant: with at most
-    ``inflight`` submissions unharvested, the buffer being packed is always
-    older than every in-flight one, so zero-copy ``device_put`` can never
-    observe a rewrite.  Shapes rotate independently — an in-flight block of
-    one shape is untouched by packing another shape.
+    ``buffer``/``pack`` hand out, per shape, a buffer not handed out since
+    the last :meth:`rewind`, allocating one when the shape has none left;
+    ``rewind`` makes them all available again.  The caller rewinds only
+    when no block that reads a buffer is in flight — at the top of a round,
+    every block of the previous one harvested (or, after a failure, waited
+    on) — so zero-copy ``device_put`` can never observe a rewrite.  While a
+    round's k-th block of a shape is packed, the k-1 before it are still in
+    flight, so the pool grows to the number of blocks in flight plus the
+    one being packed: the round's block count of that shape.  Shapes are
+    independent — packing one shape hands out none of another's buffers.
+
+    The buffers are kept for later rounds, so the pool holds the largest
+    round's blocks x slots x ``width`` x 4 B: with raw 12,800-sample
+    windows 51,200 B a slot, 52 MB for a round of 16 blocks of 64 slots.  A
+    round that drains several windows per stream
+    (``max_per_stream_per_round`` > 1) holds proportionally more.
     """
 
-    def __init__(self, width: int, inflight: int, dtype=np.float32):
+    def __init__(self, width: int, dtype=np.float32):
         if width < 1:
             raise ValueError(f"width must be >= 1, got {width}")
-        if inflight < 1:
-            raise ValueError(f"inflight must be >= 1, got {inflight}")
         self.width = int(width)
-        self.depth = int(inflight) + 1
         self.dtype = dtype
         self._pools: dict[int, list[np.ndarray]] = {}
         self._next: dict[int, int] = {}
 
+    def rewind(self) -> None:
+        """Make every buffer available again; call only when no block that
+        reads one is in flight."""
+        self._next.clear()
+
     def pack(self, rows: Sequence[np.ndarray] | np.ndarray, slots: int) -> np.ndarray:
-        """Copy ``rows`` into the next rotation buffer of shape ``(slots,
-        width)``; dead-slot tails carry zeros (silence)."""
+        """Copy ``rows`` into a free buffer of shape ``(slots, width)``;
+        dead-slot tails carry zeros (silence)."""
         block = self.buffer(slots, len(rows))
         block[: len(rows)] = rows
         return block
 
     def buffer(self, slots: int, live: int) -> np.ndarray:
-        """The next rotation buffer of shape ``(slots, width)`` for a caller
-        that writes its first ``live`` rows itself; the dead-slot tail
+        """A free buffer of shape ``(slots, width)`` for a caller that
+        writes its first ``live`` rows itself; the dead-slot tail
         ``[live:]`` already carries zeros (silence)."""
         if live > slots:
             raise ValueError(f"{live} rows do not fit {slots} slots")
-        pool = self._pools.get(slots)
-        if pool is None:
-            pool = [
-                np.zeros((slots, self.width), self.dtype)
-                for _ in range(self.depth)
-            ]
-            self._pools[slots] = pool
-            self._next[slots] = 0
-        i = self._next[slots]
-        self._next[slots] = (i + 1) % self.depth
+        pool = self._pools.setdefault(slots, [])
+        i = self._next.get(slots, 0)
+        if i == len(pool):
+            pool.append(np.zeros((slots, self.width), self.dtype))
+        self._next[slots] = i + 1
         block = pool[i]
         if live < slots:
             block[live:] = 0.0  # dead slots carry silence
@@ -220,7 +230,7 @@ class IngestQueue:
 
 
 class DispatchCore:
-    """Queue → slot-blocks → bounded in-flight rotation → ordered results.
+    """Queue → slot-blocks → the whole round in flight → ordered results.
 
     Generic over the work item and the block program:
 
@@ -228,17 +238,26 @@ class DispatchCore:
         Dispatch one block of ``slots`` slots holding ``live_items`` (at
         most ``slots`` of them; the callee pads dead slots).  May return an
         async handle (e.g. an in-flight jax array) — submission must not
-        block on the result, that is what gives the double-buffered
-        overlap.
+        block on the result, that is what lets the device work through one
+        block while the host packs, puts and launches the next.
     ``harvest(handle)``
         Block until the handle's results are ready; return an indexable of
         per-slot results (only the first ``len(live_items)`` are read).
         ``None`` means ``submit`` is synchronous and already returns the
         per-item results.
 
+    With a ``harvest`` hook every block of ``dispatch(items)`` is submitted
+    before the first is harvested, then the blocks are harvested in order:
+    the dispatch depth is the round's block count, which
+    ``depth_histogram`` (``{blocks in flight at the round's first harvest:
+    rounds}``) records.
+
     ``dispatch(items)`` is all-or-nothing: the optional ``pre_dispatch``
     hook (the fault-injection seam) runs first and may raise; any exception
-    from it, ``submit`` or ``harvest`` triggers ``on_rollback`` and
+    from it, ``submit`` or ``harvest`` — at any block, with the blocks
+    before it in flight — is raised only after every block already
+    submitted has been waited on (their results dropped, so a retried round
+    may reuse their input buffers), then triggers ``on_rollback`` and
     propagates with no partial results observable, so a transactional
     caller can simply retry the identical round.  On success ``on_commit``
     fires and every item's result is returned in input order.
@@ -250,17 +269,13 @@ class DispatchCore:
         submit: Callable[[Any, int], Any],
         harvest: Callable[[Any], Any] | None = None,
         slot_policy: SlotPolicy,
-        inflight: int = 1,
         pre_dispatch: Callable[[Any], None] | None = None,
         on_commit: Callable[[Any, list], None] | None = None,
         on_rollback: Callable[[Any], None] | None = None,
     ):
-        if inflight < 1:
-            raise ValueError(f"inflight must be >= 1, got {inflight}")
         self._submit = submit
         self._harvest = harvest
         self.slot_policy = slot_policy
-        self.inflight = int(inflight)
         self.pre_dispatch = pre_dispatch
         self.on_commit = on_commit
         self.on_rollback = on_rollback
@@ -269,6 +284,7 @@ class DispatchCore:
         self.blocks_dispatched = 0
         self.padded_slots = 0
         self.slot_histogram: dict[int, int] = {}
+        self.depth_histogram: dict[int, int] = {}
 
     # -- ready-work queue ----------------------------------------------------
 
@@ -313,35 +329,43 @@ class DispatchCore:
         n = len(items)
         results: list = [None] * n
         pending: collections.deque[tuple[int, int, Any]] = collections.deque()
-
-        def harvest_one():
-            # blocking on the oldest in-flight block also means the device
-            # has consumed its input buffer, so the BlockPool rotation may
-            # safely rewrite it on a later turn
-            start, n_live, handle = pending.popleft()
-            out = self._harvest(handle)
-            for j in range(n_live):
-                results[start + j] = out[j]
-
-        i = 0
-        while i < n:
-            slots = self.slot_policy.pick(n - i)
-            live = items[i : i + slots]
-            n_live = len(live)
-            out = self._submit(live, slots)
-            self.blocks_dispatched += 1
-            self.padded_slots += slots - n_live
-            self.slot_histogram[slots] = self.slot_histogram.get(slots, 0) + 1
-            if self._harvest is None:  # synchronous program
+        try:
+            i = 0
+            while i < n:
+                slots = self.slot_policy.pick(n - i)
+                live = items[i : i + slots]
+                n_live = len(live)
+                out = self._submit(live, slots)
+                self.blocks_dispatched += 1
+                self.padded_slots += slots - n_live
+                self.slot_histogram[slots] = self.slot_histogram.get(slots, 0) + 1
+                if self._harvest is None:  # synchronous program
+                    for j in range(n_live):
+                        results[i + j] = out[j]
+                else:
+                    pending.append((i, n_live, out))
+                i += n_live
+            if pending:
+                depth = len(pending)
+                self.depth_histogram[depth] = self.depth_histogram.get(depth, 0) + 1
+            while pending:
+                start, n_live, handle = pending.popleft()
+                out = self._harvest(handle)
                 for j in range(n_live):
-                    results[i + j] = out[j]
-            else:
-                pending.append((i, n_live, out))
-                if len(pending) >= self.inflight:
-                    harvest_one()
-            i += n_live
-        while pending:
-            harvest_one()
+                    results[start + j] = out[j]
+        except BaseException:
+            # Wait for every block still in flight before the failure
+            # propagates: once it has, nothing reads the blocks' input
+            # buffers, so the retried round may rewrite them.  A block that
+            # fails here too is dropped with the rest; the first error is
+            # the one raised.
+            while pending:
+                handle = pending.popleft()[2]
+                try:
+                    self._harvest(handle)
+                except Exception:
+                    pass
+            raise
         return results
 
 

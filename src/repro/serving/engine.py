@@ -32,11 +32,11 @@ a batch, or a batch split over a device mesh, produces the identical numbers
 (the streaming-parity and sharded-conformance tests pin this).  ``shards=k``
 routes every fixed-slot block through the ``shard_map``-based
 :func:`~repro.serving.accelerator.accelerator_forward_sharded` (weights
-replicated, activation rows split over a 1-D "streams" mesh), and dispatch
-is double-buffered: the next block is submitted while the previous block's
-device buffers are still in flight.  ``python -m repro.launch.monitor`` is
-the demo driver and ``benchmarks/bench_serving.py`` the throughput harness
-on top of this class.
+replicated, activation rows split over a 1-D "streams" mesh), and every
+block of a round is submitted before the first is harvested, so the device
+works through the round's blocks while the host packs and launches the
+next.  ``python -m repro.launch.monitor`` is the demo entry point and
+``benchmarks/bench_serving.py`` the throughput harness on top of this class.
 """
 from __future__ import annotations
 
@@ -340,8 +340,8 @@ class MonitorEngine:
     trackers and returns per-stream event lists.
 
     ``shards``/``mesh`` select sharded-batch dispatch (each block split over
-    the mesh's "streams" axis, bitwise identical results); ``inflight``
-    bounds how many blocks may be in flight before the oldest is harvested.
+    the mesh's "streams" axis, bitwise identical results).  Every block of a
+    round is in flight before the first is harvested (``depth_histogram``).
 
     ``prune``/``policy`` bake a structured channel prune and a per-layer
     precision policy into the served artifact at construction time — the
@@ -351,7 +351,7 @@ class MonitorEngine:
     ``on_device_features=True`` fuses the DSP front-end into the jitted
     program: the engine submits raw ``(slots, 12800)`` window blocks and the
     artifact's baked ``feature_kind`` front-end runs in-graph, so host
-    feature extraction no longer serializes with the double-buffered device
+    feature extraction no longer serializes with the queued device
     dispatch.  The numpy front-end stays the oracle: its float64 features
     differ from the in-graph float32 ones within a per-kind tolerance
     (``features_jax.PARITY_ATOL``), while all *within-JAX* parity guarantees
@@ -376,7 +376,6 @@ class MonitorEngine:
         interpret: bool | None = None,
         shards: int | None = None,
         mesh: jax.sharding.Mesh | None = None,
-        inflight: int = 2,
         adaptive_slots: bool = False,
         min_slots: int = 1,
         admission: AdmissionPolicy | None = None,
@@ -470,12 +469,6 @@ class MonitorEngine:
                 )
             self._qp = replicate_params(self._qp, mesh)
         self.shards = 1 if mesh is None else mesh.shape[self._mesh_axis]
-        # Double-buffered async dispatch: up to `inflight` fixed-slot blocks
-        # may be on-device concurrently; results are harvested (blocking)
-        # only when the pipeline is full or the round ends.
-        if inflight < 1:
-            raise ValueError(f"inflight must be >= 1, got {inflight}")
-        self._inflight = inflight
         self._rings = [
             StreamRing(self.window, self.hop, capacity_windows)
             for _ in range(n_streams)
@@ -490,9 +483,10 @@ class MonitorEngine:
         # The shared continuous-batching core (serving/batching.py): ladder
         # of dispatchable slot shapes (fixed = always batch_slots, adaptive
         # = power-of-two multiples of the shard count), the preallocated
-        # inflight+1 block-buffer rotation, and the slot-chunked dispatch
-        # loop with the fault seam — the machinery launch/serve.py's
-        # BatchedServer runs on too.
+        # block buffers (one per block of a round), and the slot-chunked
+        # dispatch loop with the fault seam — the machinery launch/serve.py's
+        # BatchedServer runs on too.  Dispatch is as deep as the round
+        # (see ``_forward``).
         self.slot_policy = SlotPolicy(
             batch_slots,
             adaptive=adaptive_slots,
@@ -500,12 +494,11 @@ class MonitorEngine:
             multiple=self.shards,
         )
         self.adaptive_slots = self.slot_policy.adaptive
-        self._pool = BlockPool(self._in_width, inflight)
+        self._pool = BlockPool(self._in_width)
         self._core = DispatchCore(
             submit=self._submit_rows,
             harvest=self._harvest,
             slot_policy=self.slot_policy,
-            inflight=inflight,
         )
         # Stream admission / per-tenant fairness: the defaults reproduce the
         # classic behaviour (every stream admitted, one window per stream
@@ -653,6 +646,12 @@ class MonitorEngine:
         """Blocks dispatched per slot shape (adaptive sizing observability)."""
         return dict(self._core.slot_histogram)
 
+    @property
+    def depth_histogram(self) -> dict[int, int]:
+        """``{blocks in flight at the round's first harvest: rounds}``: the
+        dispatch depth, which is each round's block count."""
+        return dict(self._core.depth_histogram)
+
     # -- scoring -------------------------------------------------------------
 
     def _submit(self, block: np.ndarray) -> jax.Array:
@@ -676,16 +675,20 @@ class MonitorEngine:
             out = accelerator_forward(
                 self._qp, x, self.cfg, interpret=self._interpret, raw_windows=raw
             )
+        # Queue the result's copy to the host behind the forward, so the
+        # round's in-order harvest finds each block's probabilities there
+        # instead of paying one device -> host round trip a block.
+        out.copy_to_host_async()
         if tel.on:
             tel.close(i, block.shape[0])
         return out
 
     def _submit_rows(self, items, slots: int) -> jax.Array:
-        """DispatchCore submit hook: fill the next rotation buffer of the
-        chosen slot shape with the live items and dispatch it.  With the
-        front-end on the device an item is a ``(ring, depth)`` pair of the
-        round's window plan, and the ring copies that window straight into
-        its row of the block; otherwise an item is a feature row."""
+        """DispatchCore submit hook: fill a buffer of the chosen slot shape
+        that no in-flight block owns with the live items and dispatch it.
+        With the front-end on the device an item is a ``(ring, depth)`` pair
+        of the round's window plan, and the ring copies that window straight
+        into its row of the block; otherwise an item is a feature row."""
         tel = self.telemetry
         if tel.on:
             i = tel.open("engine.pack")
@@ -713,9 +716,12 @@ class MonitorEngine:
         """Micro-batch the round's items — ``(n, row_width)`` feature rows,
         or the ``(ring, depth)`` window plan when the front-end is fused —
         through the shared dispatch core: the slot policy picks each block's
-        shape (fixed ``batch_slots``, or the adaptive ladder), blocks come
-        from the preallocated buffer rotation, and up to ``inflight`` blocks
-        overlap on device with harvest-time ``block_until_ready``."""
+        shape (fixed ``batch_slots``, or the adaptive ladder), each block
+        gets a preallocated buffer of its own, and every block of the round
+        is submitted before the first harvest-time ``block_until_ready``.
+        The core returns, or raises, only with no block in flight, so the
+        round starts by making every buffer available again."""
+        self._pool.rewind()
         return np.stack(self._core.dispatch(list(items)))
 
     def precompile(self) -> tuple[int, ...]:

@@ -14,7 +14,7 @@ bitwise standard, not a tolerance:
 * **execution lanes** — with ``lanes="threads"`` every worker gets a named
   lane thread that runs its engine's ingest→dispatch→harvest beat, so one
   worker's host feature extraction overlaps another worker's device
-  scoring through the dispatch core's in-flight rotation.  Ingest enters a
+  scoring through the dispatch core's queued blocks.  Ingest enters a
   shared front-of-fleet :class:`~repro.serving.batching.IngestQueue` and
   is routed to workers through the ``_route`` table at the top of each
   round on the supervisor thread, so delivery (admission, chunk faults,

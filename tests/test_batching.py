@@ -1,6 +1,6 @@
 """The shared continuous-batching core (serving/batching.py) in isolation:
-slot-ladder selection, the rotating block pool's aliasing-safety contract,
-the dispatch loop's ordering/padding/in-flight behaviour and its
+slot-ladder selection, the block pool's aliasing-safety contract, the
+dispatch loop's ordering/padding/in-flight behaviour and its
 all-or-nothing commit/rollback semantics, and the admission/fairness
 primitives the fleet-scale monitor builds on.  The engine- and server-level
 suites (test_streaming_engine.py, test_serve.py, test_fault_tolerance.py)
@@ -93,35 +93,64 @@ def test_adaptive_total_padding_bounded_by_ladder():
 
 
 def test_block_pool_rotation_depth():
-    pool = BlockPool(width=3, inflight=2)
+    """Each block handed out since the last rewind has a buffer of its own;
+    after ``rewind`` the pool hands the same buffers out again, in order,
+    and grows only when a round holds more blocks than any before it."""
+    pool = BlockPool(width=3)
     rows = [np.full(3, i, np.float32) for i in range(10)]
     b0 = pool.pack(rows[:2], 4)
     b1 = pool.pack(rows[2:4], 4)
     b2 = pool.pack(rows[4:6], 4)
-    # three distinct buffers (inflight + 1), then the rotation reuses b0
     assert b0 is not b1 and b1 is not b2 and b0 is not b2
+    pool.rewind()
     assert pool.pack(rows[6:8], 4) is b0
+    assert pool.pack(rows[8:10], 4) is b1
+    assert pool.pack(rows[:1], 4) is b2
+    b3 = pool.pack(rows[1:2], 4)  # a deeper round: one more buffer
+    assert all(b3 is not b for b in (b0, b1, b2))
+
+
+@pytest.mark.parametrize("k", [1, 2, 5, 16])
+def test_block_pool_outstanding_blocks_never_alias(k):
+    """With k blocks of one shape outstanding (handed out, not rewound),
+    no buffer handed out aliases any of the k — also while another shape's
+    blocks are handed out in between, and again in the next round."""
+    pool = BlockPool(width=4)
+    for _ in range(2):  # a second round reuses the first round's buffers
+        pool.rewind()
+        held = []
+        for j in range(k):
+            held.append(pool.buffer(8, 8))
+            other = pool.buffer(2, 1)  # another shape: its own buffers
+            assert not any(np.shares_memory(other, h) for h in held)
+        for a in range(k):
+            for b in range(a + 1, k):
+                assert not np.shares_memory(held[a], held[b])
 
 
 def test_block_pool_zeroes_dead_tail():
-    pool = BlockPool(width=2, inflight=1)
+    pool = BlockPool(width=2)
     full = pool.pack([np.ones(2, np.float32)] * 3, 3)
     np.testing.assert_array_equal(full, np.ones((3, 2), np.float32))
+    pool.rewind()
     partial = pool.pack([np.full(2, 7.0, np.float32)], 3)
+    assert partial is full
     np.testing.assert_array_equal(partial[0], np.full(2, 7.0, np.float32))
     np.testing.assert_array_equal(partial[1:], np.zeros((2, 2), np.float32))
 
 
 def test_block_pool_buffer_shares_the_rotation_and_zeroes_the_dead_tail():
-    """``buffer`` hands out the same ``inflight + 1`` rotation as ``pack``,
-    for a caller that fills the live rows itself: a reused buffer's dead
-    tail is silence again, its live rows are left to the caller."""
-    pool = BlockPool(width=2, inflight=1)
+    """``buffer`` hands out the same buffers as ``pack``, for a caller that
+    fills the live rows itself: a reused buffer's dead tail is silence
+    again, its live rows are left to the caller."""
+    pool = BlockPool(width=2)
     a = pool.pack([np.full(2, 5.0, np.float32)] * 3, 3)
     b = pool.buffer(3, 3)
     b[:] = 9.0
+    assert b is not a
+    pool.rewind()
     again = pool.buffer(3, 1)
-    assert again is a and b is not a  # depth 2: the rotation came round
+    assert again is a  # the next round starts from the first buffer
     np.testing.assert_array_equal(again[0], np.full(2, 5.0, np.float32))
     np.testing.assert_array_equal(again[1:], np.zeros((2, 2), np.float32))
     assert pool.pack([np.ones(2, np.float32)], 3) is b
@@ -130,12 +159,14 @@ def test_block_pool_buffer_shares_the_rotation_and_zeroes_the_dead_tail():
 
 
 def test_block_pool_shapes_rotate_independently():
-    pool = BlockPool(width=1, inflight=1)
+    pool = BlockPool(width=1)
     a = pool.pack([np.zeros(1, np.float32)], 2)
     b = pool.pack([np.zeros(1, np.float32)], 4)  # other shape: fresh pool
     c = pool.pack([np.ones(1, np.float32)], 2)
     assert a.shape == (2, 1) and b.shape == (4, 1)
-    assert a is not c  # shape-2 rotation advanced, untouched by shape-4
+    assert a is not c  # shape 2 handed out its second buffer
+    pool.rewind()
+    assert pool.pack([np.zeros(1, np.float32)], 4) is b  # untouched by shape 2
     with pytest.raises(ValueError, match="do not fit"):
         pool.pack([np.zeros(1, np.float32)] * 3, 2)
 
@@ -170,6 +201,7 @@ def test_dispatch_preserves_input_order_and_chunks():
     assert core.blocks_dispatched == 3
     assert core.padded_slots == 2  # final block: 2 live in 4 slots
     assert core.slot_histogram == {4: 3}
+    assert core.depth_histogram == {}  # synchronous: nothing in flight
 
 
 def test_dispatch_adaptive_shrinks_tail():
@@ -181,29 +213,88 @@ def test_dispatch_adaptive_shrinks_tail():
     assert core.slot_histogram == {4: 1, 2: 1, 1: 1}
 
 
-def test_async_harvest_bounded_inflight():
-    in_flight = []
-    max_depth = []
+def _async_core(slots=2, adaptive=False, fail_at=None, **kw):
+    """Core over an async 'program': ``submit`` returns a handle, nothing
+    is computed until ``harvest``.  ``events`` records both in order;
+    ``fail_at = ("submit" | "harvest", k)`` raises at the k-th (1-based)
+    call of that hook, once."""
+    events, in_flight, calls = [], [], {"submit": 0, "harvest": 0}
 
-    def submit(live, slots):
+    def hook_called(name):
+        calls[name] += 1
+        if fail_at == (name, calls[name]):
+            raise RuntimeError("injected")
+
+    def submit(live, n_slots):
+        events.append(("submit", list(live)))
+        hook_called("submit")
         handle = [x + 100 for x in live]
         in_flight.append(handle)
-        max_depth.append(len(in_flight))
         return handle
 
     def harvest(handle):
+        events.append(("harvest", handle))
         in_flight.remove(handle)
+        hook_called("harvest")
         return handle
 
     core = DispatchCore(
         submit=submit, harvest=harvest,
-        slot_policy=SlotPolicy(2), inflight=2,
+        slot_policy=SlotPolicy(slots, adaptive=adaptive), **kw,
     )
-    out = core.dispatch(list(range(9)))
-    assert out == [x + 100 for x in range(9)]
-    # the pipeline never holds more than `inflight` unharvested blocks
-    assert max(max_depth) == 2
+    return core, events, in_flight
+
+
+@pytest.mark.parametrize(
+    "n, slots, adaptive, blocks",
+    [(9, 2, False, 5), (1, 2, False, 1), (7, 4, True, 3), (64, 8, False, 8)],
+)
+def test_async_harvest_bounded_inflight(n, slots, adaptive, blocks):
+    """The in-flight depth is the round's own block count: every block is
+    submitted before the first harvest, the blocks are harvested in order,
+    and the results come back in input order."""
+    core, events, in_flight = _async_core(slots, adaptive)
+    out = core.dispatch(list(range(n)))
+    assert out == [x + 100 for x in range(n)]
+    kinds = [e[0] for e in events]
+    assert kinds == ["submit"] * blocks + ["harvest"] * blocks
+    submitted = [[x + 100 for x in e[1]] for e in events[:blocks]]
+    assert [e[1] for e in events[blocks:]] == submitted  # harvest in order
     assert not in_flight  # everything harvested by the end
+    assert core.depth_histogram == {blocks: 1}
+    core.dispatch(list(range(n)))
+    assert core.depth_histogram == {blocks: 2}
+
+
+@pytest.mark.parametrize("fail_at", [("submit", 4), ("harvest", 2)])
+def test_failed_block_waits_for_blocks_in_flight_and_retries(fail_at):
+    """A failure at the 4th of 5 submits, or at the 2nd harvest with every
+    block submitted, is raised only after each block still in flight was
+    waited on; their results are dropped, nothing is committed, the
+    rollback fires, and the retried round returns what a clean one does."""
+    committed, rolled = [], []
+    core, events, in_flight = _async_core(
+        2, fail_at=fail_at,
+        on_commit=lambda items, res: committed.append(list(res)),
+        on_rollback=lambda items: rolled.append(list(items)),
+    )
+    items = list(range(9))
+    with pytest.raises(RuntimeError, match="injected"):
+        core.dispatch(items)
+    assert not in_flight  # every submitted block was waited on
+    assert committed == [] and rolled == [items]
+    kinds = [e[0] for e in events]
+    if fail_at[0] == "submit":
+        # 3 blocks in flight, the 4th submit raised: the 3 are harvested
+        assert kinds == ["submit"] * 4 + ["harvest"] * 3
+        assert core.depth_histogram == {}  # no round reached a harvest
+    else:
+        assert kinds == ["submit"] * 5 + ["harvest"] * 5
+        assert core.depth_histogram == {5: 1}
+    events.clear()
+    assert core.dispatch(items) == [x + 100 for x in items]
+    assert committed == [[x + 100 for x in items]]
+    assert [e[0] for e in events] == ["submit"] * 5 + ["harvest"] * 5
 
 
 def test_enqueue_drain_fifo_and_requeue_on_failure():

@@ -429,12 +429,16 @@ def _assert_same_state(a, b):
             np.testing.assert_array_equal(a[2][key], b[2][key])
 
 
-@pytest.mark.parametrize("fault", ["fault_hook", "second_block_submit"])
+@pytest.mark.parametrize(
+    "fault", ["fault_hook", "second_block_submit", "last_block_submit"]
+)
 def test_on_device_failed_round_is_untouched_and_retries_identically(fault):
     """A round that raises — in the fault seam before anything is copied,
-    or in the submit of its second block after the first block's windows
-    were copied and sent — leaves rings, ready counts and tracker as they
-    were, and the retried round scores exactly what a clean engine does."""
+    in the submit of its second block after the first block's windows were
+    copied and sent, or in the submit of its last block with every earlier
+    block in flight — leaves rings, ready counts and tracker as they were,
+    waits for the blocks it had sent, and the retried round scores exactly
+    what a clean engine does."""
     cfg, params = _small_detector()
     n_streams = 3
 
@@ -454,9 +458,14 @@ def test_on_device_failed_round_is_untouched_and_retries_identically(fault):
         return [(w.stream, w.window_idx, w.p_uav, w.smoothed, w.active) for w in out]
 
     clean = build()
-    want = [scores(clean.step()), scores(clean.step())]
+    want = [scores(clean.step())]
+    n_blocks = clean.forward_calls  # blocks in the first round
+    want.append(scores(clean.step()))
+    assert n_blocks == 3
     engine = build()
+    engine.telemetry.on = True
     before = _round_state(engine)
+    fail_at = {"second_block_submit": 2, "last_block_submit": n_blocks}.get(fault)
     if fault == "fault_hook":
         def boom(items):
             raise RuntimeError("injected")
@@ -466,13 +475,17 @@ def test_on_device_failed_round_is_untouched_and_retries_identically(fault):
 
         def submit(block):
             calls.append(block.shape[0])
-            if len(calls) == 2:
+            if len(calls) == fail_at:
                 raise RuntimeError("injected")
             return real_submit(block)
         engine._submit = submit
     with pytest.raises(RuntimeError, match="injected"):
         engine.step()
     _assert_same_state(_round_state(engine), before)
+    # every block sent before the failure was waited on before it propagated
+    waited = len(engine.telemetry.arrays("engine.wait")[0])
+    assert waited == (0 if fail_at is None else fail_at - 1)
+    assert engine.served_windows.sum() == 0
     engine.fault_hook = None
     engine.__dict__.pop("_submit", None)
     assert [scores(engine.step()), scores(engine.step())] == want
@@ -524,40 +537,65 @@ def test_engine_rejects_artifact_without_feature_kind():
         )
 
 
-def test_engine_block_buffer_reuse_is_invisible():
-    """The preallocated rotating dispatch buffers must behave exactly like
-    the old fresh-np.zeros-per-chunk blocks: many rounds with varying ready
-    counts (full blocks, partial tails after full blocks) stay bitwise equal
-    to a per-stream batched reference, for both inflight depths."""
+@pytest.mark.parametrize("on_device", [False, True])
+@pytest.mark.parametrize("per_round", [1, 4])
+def test_engine_block_buffer_reuse_is_invisible(per_round, on_device):
+    """The preallocated dispatch buffers must behave exactly like the old
+    fresh-np.zeros-per-chunk blocks: many rounds with varying ready counts
+    (full blocks, partial tails after full blocks), each round's blocks all
+    queued before the first harvest, stay bitwise equal to a per-stream
+    batched reference.  ``per_round=4`` drains the whole backlog in one
+    round of 10 blocks in flight at once."""
     cfg, params = _small_detector()
     rng = np.random.default_rng(4)
     n_streams, n_win = 5, 4
     audio = rng.standard_normal(
         (n_streams, n_win * features.N_SAMPLES)
     ).astype(np.float32)
+    engine = MonitorEngine(
+        params, cfg, n_streams=n_streams, feature_kind="zcr",
+        on_device_features=on_device, batch_slots=2,
+        admission=AdmissionPolicy(max_per_stream_per_round=per_round),
+    )
+    served = engine._qp if on_device else params  # the fused front-end's bake
     ref = {}
     for s in range(n_streams):
-        feats = features.batch_features(
-            audio[s].reshape(n_win, features.N_SAMPLES), "zcr"
-        )
+        wins = audio[s].reshape(n_win, features.N_SAMPLES)
+        x = wins if on_device else features.batch_features(wins, "zcr")
         ref[s] = np.asarray(
-            accelerator_forward(params, jnp.asarray(feats), cfg)
+            accelerator_forward(served, jnp.asarray(x), cfg, raw_windows=on_device)
         )[:, 1].astype(np.float64)
-    for inflight in (1, 2):
-        engine = MonitorEngine(
-            params, cfg, n_streams=n_streams, feature_kind="zcr",
-            batch_slots=2, inflight=inflight,
-        )
-        # round 1 fills both slots of the last block (5 ready -> 2+2+1: the
-        # stale-tail case), later rounds rewrite previously-padded buffers
-        scores: dict[int, list[float]] = {s: [] for s in range(n_streams)}
-        for w in range(n_win):
-            for s in range(n_streams):
-                engine.push(s, audio[s, w * features.N_SAMPLES : (w + 1) * features.N_SAMPLES])
-        for ws in engine.drain():
-            scores[ws.stream].append(ws.p_uav)
+    # round 1 fills both slots of the last block (5 ready -> 2+2+1: the
+    # stale-tail case), later rounds rewrite previously-padded buffers
+    scores: dict[int, list[float]] = {s: [] for s in range(n_streams)}
+    for w in range(n_win):
         for s in range(n_streams):
-            np.testing.assert_array_equal(np.asarray(scores[s], np.float64), ref[s])
+            engine.push(s, audio[s, w * features.N_SAMPLES : (w + 1) * features.N_SAMPLES])
+    for ws in engine.drain():
+        scores[ws.stream].append(ws.p_uav)
+    for s in range(n_streams):
+        np.testing.assert_array_equal(np.asarray(scores[s], np.float64), ref[s])
+    # every round queued all its blocks: 3 a round (2+2+1), or all 10 at once
+    assert engine.depth_histogram == ({3: n_win} if per_round == 1 else {10: 1})
+
+
+def test_engine_depth_histogram_counts_rounds_of_full_blocks():
+    """``depth_histogram`` reads ``{k: rounds}`` for rounds of k full
+    blocks: 8 streams over 2 slots queue 4 blocks a round."""
+    cfg, params = _small_detector()
+    engine = MonitorEngine(
+        params, cfg, n_streams=8, feature_kind="zcr", on_device_features=True,
+        batch_slots=2,
+    )
+    assert engine.depth_histogram == {}
+    rng = np.random.default_rng(2)
+    for s in range(8):
+        engine.push(s, rng.standard_normal(3 * features.N_SAMPLES).astype(np.float32))
+    assert len(engine.drain()) == 24
+    assert engine.depth_histogram == {4: 3}
+    assert engine.slot_histogram == {2: 12} and engine.padded_slots == 0
+    # each round rewinds the pool: it holds one round's 4 buffers, not 12
+    assert len(engine._pool._pools[2]) == 4
 
 
 def test_engine_dropped_samples_incremental_counter():
