@@ -13,7 +13,6 @@ def main() -> None:
     from benchmarks import (
         bench_fig45,
         bench_kernels,
-        bench_serving,
         bench_table1,
         bench_table2,
         bench_table34,
@@ -29,7 +28,6 @@ def main() -> None:
         ("table34", bench_table34.main),
         ("table5", bench_table5.main),
         ("kernels", bench_kernels.main),
-        ("serving", bench_serving.main),
         ("roofline", roofline.main),
     ]
     failures = []

@@ -14,7 +14,7 @@ Phases, in order, each printing its own line(s):
 * ``served``  — ``CNNConfig()`` with seeded random weights, baked into two
   artifacts (uniform int8, and pruned 8,704-flatten with the mixed policy),
   each served to 64 synthetic scene streams in uneven chunks through
-  ``MonitorEngine(on_device_features=True, batch_slots=64)`` and again
+  ``MonitorEngine(batch_slots=64)`` (raw windows, fused front-end) and again
   through a 2-worker ``FleetSupervisor``.  Every delivered window must be
   scored; the engine's and the fleet's scores, and a lone window's, must
   equal one batched ``accelerator_forward`` bitwise; probabilities must be
@@ -273,7 +273,7 @@ def served_phase(arts, traffic) -> None:
 
     cfg = arts["cfg"]
     scenes, schedule, windows, per_stream = traffic
-    kw = dict(feature_kind="mfcc20", on_device_features=True, batch_slots=SLOTS)
+    kw = dict(feature_kind="mfcc20", batch_slots=SLOTS)
     for name in ("int8", "pruned_mixed"):
         qp, ref = arts[name]["qp"], arts[name]["ref"]
         engine = MonitorEngine(qp, cfg, n_streams=STREAMS, **kw)
@@ -346,7 +346,7 @@ def sharded_phase(arts, traffic, shards: int) -> None:
         probs = batched_forward(qp, cfg, windows)  # one chip
         engine = MonitorEngine(
             qp, cfg, n_streams=STREAMS, feature_kind="mfcc20",
-            on_device_features=True, batch_slots=SLOTS, shards=shards,
+            batch_slots=SLOTS, shards=shards,
         )
         t0 = time.perf_counter()
         engine.precompile()

@@ -66,7 +66,6 @@ SMOKE=1 BENCH_OUT="$BENCH_FRESH" python -m benchmarks.bench_kernels
 python scripts/perf_gate.py --fresh "$BENCH_FRESH" \
   --require 'kernels/conv_layer_fused_*' \
   --require 'kernels/frontend_jax_*'
-SMOKE=1 python -m benchmarks.bench_serving
 
 # Sharded-driver smoke: the --shards path boots 2 simulated devices and
 # must produce windows end-to-end (random weights: plumbing only, fast).
@@ -77,10 +76,6 @@ python -m repro.launch.monitor --seconds 2 --shards 2 --random
 # monitor driver (random weights: plumbing only, fast).
 python -m repro.launch.monitor --seconds 2 --prune 2 \
   --policy "conv0/w=bf16,dense1/w=fp32" --random
-
-# On-device front-end smoke: raw-window dispatch with the DSP front-end
-# fused into the jitted program (random weights: plumbing only, fast).
-python -m repro.launch.monitor --seconds 2 --device-features --random
 
 # High-stream adaptive smoke: 256 streams through the shared dispatch core
 # on the adaptive slot ladder — proves the fleet-scale admission/fairness

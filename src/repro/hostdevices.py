@@ -4,9 +4,8 @@ Sharded-batch dispatch on CPU needs N simulated XLA devices, and
 ``--xla_force_host_platform_device_count`` only takes effect if it is in
 ``XLA_FLAGS`` *before* the first jax import.  This module is deliberately
 jax-import-free so drivers and benches can call it at module-load time;
-everything that needs the override (``launch/monitor``,
-``benchmarks/bench_serving``) routes through here instead of hand-rolling
-the env append.
+what needs the override (``launch/monitor``) routes through here instead
+of hand-rolling the env append.
 """
 from __future__ import annotations
 

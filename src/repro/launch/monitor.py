@@ -92,10 +92,6 @@ def main(argv=None):
                          "(sharded-batch dispatch; bitwise-identical results)")
     ap.add_argument("--feature", default=None, choices=sorted(features.FEATURE_DIMS),
                     help="feature set (default: psd, or mfcc20 with --trained)")
-    ap.add_argument("--device-features", action="store_true",
-                    help="fuse the DSP front-end into the jitted device "
-                         "program (engine submits raw windows; no host "
-                         "feature extraction on the serving path)")
     ap.add_argument("--slots", type=int, default=8, help="micro-batch slot count")
     ap.add_argument("--adaptive-slots", action="store_true",
                     help="grow/shrink micro-batch blocks over a power-of-two "
@@ -117,7 +113,7 @@ def main(argv=None):
     ap.add_argument("--lanes", choices=("threads",), default=None,
                     help="give each fleet worker a named execution lane "
                          "(thread) so workers' rounds overlap — host "
-                         "feature extraction for one worker overlaps device "
+                         "block packing for one worker overlaps device "
                          "scoring for another (bitwise-identical results); "
                          "implies --workers 2 unless given")
     ap.add_argument("--autoscale", action="store_true",
@@ -219,7 +215,7 @@ def main(argv=None):
         # decisions here instead of inside the engine.
         qp = quantize_params(
             params, cfg, mode=args.precision, prune=prune_spec, policy=policy,
-            feature_kind=args.feature if args.device_features else None,
+            feature_kind=args.feature,
         )
         n_workers = args.workers if args.workers is not None else 2
         sup_kw = dict(
@@ -230,7 +226,6 @@ def main(argv=None):
             checkpoint_interval=args.checkpoint_interval,
             sanitize=SanitizePolicy(),
             feature_kind=args.feature,
-            on_device_features=args.device_features,
             batch_slots=args.slots,
             shards=args.shards,
             adaptive_slots=args.adaptive_slots,
@@ -268,7 +263,6 @@ def main(argv=None):
             params, cfg,
             n_streams=args.streams,
             feature_kind=args.feature,
-            on_device_features=args.device_features,
             batch_slots=args.slots,
             precision=args.precision,
             prune=prune_spec,
@@ -299,8 +293,6 @@ def main(argv=None):
         print(f"monitor: adaptive slots, pre-jitted ladder {list(ladder)}")
     if args.shards:
         print(f"monitor: sharded dispatch over {args.shards} device(s)")
-    if args.device_features:
-        print(f"monitor: on-device {args.feature} front-end (raw-window dispatch)")
 
     rng = np.random.default_rng(args.seed + 1)
     scenes, truths = zip(*(synth_scene(args.duration, rng) for _ in range(args.streams)))

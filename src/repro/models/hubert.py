@@ -163,17 +163,13 @@ def _encoder_layer(cfg: HubertConfig, h, lw):
         return h + _dense(x, lw["ff2_w"]) + lw["ff2_b"]
 
 
-def forward(art: HubertParams, x: jax.Array, raw_windows: bool) -> jax.Array:
-    """(B, 12800) windows -> (B, num_labels) class probabilities, traceable.
-
-    ``raw_windows`` runs the input normalisation in-graph; without it ``x``
-    holds windows the host normalised already (``features`` kind
-    ``waveform``)."""
+def forward(art: HubertParams, x: jax.Array) -> jax.Array:
+    """(B, 12800) raw windows -> (B, num_labels) class probabilities,
+    traceable; the input normalisation runs in-graph."""
     cfg, w = art.cfg, art.weights
     eps = cfg.layer_norm_eps
-    if raw_windows:
-        with jax.named_scope("frontend"):
-            x = features_jax.feature_rows(x, "waveform")
+    with jax.named_scope("frontend"):
+        x = features_jax.feature_rows(x, "waveform")
     with jax.named_scope("waveform"):
         h = x[:, :, None]
         for c, s in zip(w["conv"], cfg.conv_stride):

@@ -185,15 +185,15 @@ def _dense_layer(h, layer, lmode, act, act_axis, per_sample_acts, interpret):
     return jnp.maximum(h, 0.0) if act == "relu" else h
 
 
-@functools.partial(jax.jit, static_argnames=("raw_windows",))
-def _forward_verifier(art: HubertParams, x: jax.Array, raw_windows: bool = False) -> jax.Array:
+@jax.jit
+def _forward_verifier(art: HubertParams, x: jax.Array) -> jax.Array:
     """The HuBERT verifier's served program (:func:`repro.models.hubert.forward`),
     with a batch below ``MIN_ROWS`` padded as the CNN's is."""
     n_rows = x.shape[0]
     if 0 < n_rows < MIN_ROWS:
         with jax.named_scope("frontend"):
             x = jnp.pad(x, ((0, MIN_ROWS - n_rows), (0, 0)), mode="edge")
-    return hubert.forward(art, x, raw_windows)[:n_rows]
+    return hubert.forward(art, x)[:n_rows]
 
 
 def _check_raw_windows(qp: QuantizedParams, x: jax.Array, feature_kind: str | None):
@@ -233,8 +233,9 @@ def accelerator_forward(
 
     With a :class:`~repro.models.hubert.HubertConfig` the HuBERT verifier
     serves instead: ``params`` is its baked ``HubertParams`` (or the float
-    checkpoint, baked here), ``x`` holds (B, 12800) windows, raw or already
-    normalised, and the quantisation options do not apply.
+    checkpoint, baked here), ``x`` holds (B, 12800) raw windows
+    (``raw_windows=True`` is required: the verifier's input normalisation
+    always runs in-graph), and the quantisation options do not apply.
 
     Pass a :class:`QuantizedParams` artifact to serve from the weight cache
     (zero weight-quantisation work per call) — pruned and mixed-precision
@@ -255,12 +256,16 @@ def accelerator_forward(
     A/B surface for the mixed-loudness regression tests).
     """
     if isinstance(cfg, HubertConfig):
+        if not raw_windows:
+            raise ValueError(
+                "the verifier serves raw windows only: pass raw_windows=True"
+            )
         if x.ndim != 2 or x.shape[1] != cfg.input_len:
             raise ValueError(
                 f"the verifier takes (B, {cfg.input_len}) windows, got {tuple(x.shape)}"
             )
         art = params if isinstance(params, HubertParams) else hubert.bake(params, cfg)
-        return _forward_verifier(art, x, raw_windows)
+        return _forward_verifier(art, x)
     if isinstance(params, QuantizedParams):
         qp = params
     else:
@@ -368,7 +373,6 @@ def precompile_slot_shapes(
     cfg: CNNConfig | HubertConfig,
     slot_counts,
     *,
-    row_width: int | None = None,
     mesh: Mesh | None = None,
     axis_name: str | None = None,
     interpret: bool | None = None,
@@ -390,8 +394,7 @@ def precompile_slot_shapes(
             f"precompile_slot_shapes needs a baked QuantizedParams or HubertParams artifact, "
             f"got {type(qp).__name__}"
         )
-    if row_width is None:
-        row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
+    row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
     for slots in sorted(set(int(s) for s in slot_counts)):
         x = jnp.zeros((slots, row_width), jnp.float32)
         if mesh is not None:
@@ -466,7 +469,6 @@ def forward_scopes(
     cfg: CNNConfig | HubertConfig,
     slot_counts,
     *,
-    row_width: int | None = None,
     mesh: Mesh | None = None,
     axis_name: str | None = None,
     interpret: bool | None = None,
@@ -476,14 +478,13 @@ def forward_scopes(
     shape, as :func:`precompile_slot_shapes` builds it: the map from a
     device trace's operation names to the forward's layers (the CNN's or,
     for a ``HubertParams`` artifact, the verifier's)."""
-    if row_width is None:
-        row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
+    row_width = features_jax.N_SAMPLES if raw_windows else cfg.input_len
     interp = resolve_interpret(interpret)
     out: dict[str, str] = {}
     for slots in sorted(set(int(s) for s in slot_counts)):
         x = jax.ShapeDtypeStruct((slots, row_width), jnp.float32)
         if isinstance(qp, HubertParams):
-            lowered = _forward_verifier.lower(qp, x, raw_windows)
+            lowered = _forward_verifier.lower(qp, x)
         elif mesh is not None:
             axis = STREAM_AXIS if axis_name is None else axis_name
             lowered = _forward_sharded.lower(qp, x, mesh, axis, interp, True, raw_windows)

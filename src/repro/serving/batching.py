@@ -61,7 +61,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-from typing import Any, Callable, Sequence
+from typing import Any, Callable
 
 import numpy as np
 
@@ -142,7 +142,7 @@ class BlockPool:
     """Preallocated ``(slots, width)`` dispatch buffers, allocated lazily
     per slot shape, each owned by one in-flight block until it is harvested.
 
-    ``buffer``/``pack`` hand out, per shape, a buffer not handed out since
+    ``buffer`` hands out, per shape, a buffer not handed out since
     the last :meth:`rewind`, allocating one when the shape has none left;
     ``rewind`` makes them all available again.  The caller rewinds only
     when no block that reads a buffer is in flight — at the top of a round,
@@ -172,13 +172,6 @@ class BlockPool:
         """Make every buffer available again; call only when no block that
         reads one is in flight."""
         self._next.clear()
-
-    def pack(self, rows: Sequence[np.ndarray] | np.ndarray, slots: int) -> np.ndarray:
-        """Copy ``rows`` into a free buffer of shape ``(slots, width)``;
-        dead-slot tails carry zeros (silence)."""
-        block = self.buffer(slots, len(rows))
-        block[: len(rows)] = rows
-        return block
 
     def buffer(self, slots: int, live: int) -> np.ndarray:
         """A free buffer of shape ``(slots, width)`` for a caller that

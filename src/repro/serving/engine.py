@@ -8,9 +8,11 @@ This module scales that loop to N concurrent streams:
 
 * **per-stream ring buffers** (:class:`StreamRing`) absorb raw audio pushed
   in arbitrary chunk sizes and emit hop-aligned 0.8 s windows;
-* **continuous micro-batching** packs each round's ready windows into slot
-  blocks of one jitted :func:`~repro.serving.accelerator.accelerator_forward`
-  program (the detector's, or the HuBERT verifier's for a second stage) via
+* **continuous micro-batching** copies each round's ready windows, ring by
+  ring, into slot blocks of one jitted
+  :func:`~repro.serving.accelerator.accelerator_forward` program (the
+  detector's, or the HuBERT verifier's for a second stage) that starts at
+  the raw samples: the artifact's DSP front-end runs in-graph, via
   the shared :class:`~repro.serving.batching.DispatchCore` (the same core
   ``launch/serve.py``'s ``BatchedServer`` runs on): fixed
   ``batch_slots`` blocks with silence-padded dead slots by default, or —
@@ -36,7 +38,7 @@ replicated, activation rows split over a 1-D "streams" mesh), and every
 block of a round is submitted before the first is harvested, so the device
 works through the round's blocks while the host packs and launches the
 next.  ``python -m repro.launch.monitor`` is the demo entry point and
-``benchmarks/bench_serving.py`` the throughput harness on top of this class.
+``chipbench/run.py`` measures this class on the chip.
 """
 from __future__ import annotations
 
@@ -348,14 +350,15 @@ class MonitorEngine:
     engine then serves the paper's deployed configuration (pruned flatten,
     mixed per-layer modes) with every parity guarantee intact.
 
-    ``on_device_features=True`` fuses the DSP front-end into the jitted
-    program: the engine submits raw ``(slots, 12800)`` window blocks and the
-    artifact's baked ``feature_kind`` front-end runs in-graph, so host
-    feature extraction no longer serializes with the queued device
-    dispatch.  The numpy front-end stays the oracle: its float64 features
-    differ from the in-graph float32 ones within a per-kind tolerance
-    (``features_jax.PARITY_ATOL``), while all *within-JAX* parity guarantees
-    (streaming == batched == sharded) remain bitwise.
+    The DSP front-end is part of the jitted program: the engine submits raw
+    ``(slots, 12800)`` window blocks and the artifact's baked
+    ``feature_kind`` front-end runs in-graph, so a baked ``QuantizedParams``
+    must carry ``feature_kind`` (an fp32 checkpoint is baked with it here).
+    The numpy front-end (:mod:`repro.data.features`) is the tests' float64
+    oracle: its features differ from the in-graph float32 ones within a
+    per-kind tolerance (``features_jax.PARITY_ATOL``), while every parity
+    guarantee inside the program (streaming == batched == sharded) is
+    bitwise.  ``on_device_features`` is accepted only as ``True``.
     """
 
     def __init__(
@@ -365,7 +368,7 @@ class MonitorEngine:
         *,
         n_streams: int,
         feature_kind: str = "mfcc20",
-        on_device_features: bool = False,
+        on_device_features: bool = True,
         hop_samples: int | None = None,
         batch_slots: int = 8,
         precision: str = "int8",
@@ -389,6 +392,11 @@ class MonitorEngine:
                 f"model input_len {cfg.input_len} != {feature_kind} feature "
                 f"dim {features.FEATURE_DIMS[feature_kind]}"
             )
+        if not on_device_features:
+            raise ValueError(
+                "the host front-end was removed: the engine always serves raw "
+                "windows through the artifact's fused front-end"
+            )
         if n_streams < 1:
             raise ValueError(f"n_streams must be >= 1, got {n_streams}")
         if batch_slots < 1:
@@ -396,13 +404,9 @@ class MonitorEngine:
         self.cfg = cfg
         self.n_streams = n_streams
         self.feature_kind = feature_kind
-        self.on_device_features = on_device_features
         self.batch_slots = batch_slots
         self.window = features.N_SAMPLES
         self.hop = hop_samples if hop_samples is not None else features.N_SAMPLES
-        # Width of one micro-batch row: raw samples when the front-end is
-        # fused into the device program, extracted features otherwise.
-        self._in_width = features.N_SAMPLES if on_device_features else cfg.input_len
         self._interpret = resolve_interpret(interpret)
         # The served artifact: either pre-baked, or baked here from the fp32
         # checkpoint with the deployment decisions (default precision, prune
@@ -427,9 +431,9 @@ class MonitorEngine:
                     "applied to an already-baked QuantizedParams artifact; "
                     "pass the fp32 checkpoint instead"
                 )
-            if on_device_features and params.feature_kind != feature_kind:
+            if params.feature_kind != feature_kind:
                 raise ValueError(
-                    f"on_device_features=True needs an artifact baked for "
+                    f"the engine serves an artifact baked for "
                     f"feature kind {feature_kind!r}, got "
                     f"{params.feature_kind!r}; re-bake with "
                     f"quantize_params(..., feature_kind={feature_kind!r})"
@@ -438,7 +442,7 @@ class MonitorEngine:
         else:
             self._qp = quantize_params(
                 params, cfg, mode=precision, prune=prune, policy=policy,
-                feature_kind=feature_kind if on_device_features else None,
+                feature_kind=feature_kind,
             )
         # Sharded-batch dispatch: split each fixed-slot block along a 1-D
         # device mesh ("streams" axis), weights replicated.  `shards=None`
@@ -494,7 +498,7 @@ class MonitorEngine:
             multiple=self.shards,
         )
         self.adaptive_slots = self.slot_policy.adaptive
-        self._pool = BlockPool(self._in_width)
+        self._pool = BlockPool(self.window)
         self._core = DispatchCore(
             submit=self._submit_rows,
             harvest=self._harvest,
@@ -530,10 +534,9 @@ class MonitorEngine:
         self.refused_chunks = np.zeros(n_streams, np.int64)
         # Host spans at every boundary of push/step (off by default; not
         # part of snapshot()): engine.push counts samples, engine.step the
-        # windows scored, engine.gather (the round's window plan, or the
-        # stacked windows and host features when off-device) the bytes the
-        # round reads, engine.pack (filling the blocks) the live rows,
-        # engine.put the bytes handed to the device,
+        # windows scored, engine.gather (the round's window plan) the bytes
+        # the round reads, engine.pack (copying windows ring -> block) the
+        # live rows, engine.put the bytes handed to the device,
         # engine.launch and engine.wait the slots, engine.tracker and
         # engine.commit the windows.
         self.telemetry = Telemetry()
@@ -664,16 +667,15 @@ class MonitorEngine:
         if tel.on:
             tel.close(i, block.nbytes)
             i = tel.open("engine.launch")
-        raw = self.on_device_features
         if self._mesh is not None:
             out = accelerator_forward_sharded(
                 self._qp, x, self.cfg, mesh=self._mesh,
                 axis_name=self._mesh_axis, interpret=self._interpret,
-                raw_windows=raw,
+                raw_windows=True,
             )
         else:
             out = accelerator_forward(
-                self._qp, x, self.cfg, interpret=self._interpret, raw_windows=raw
+                self._qp, x, self.cfg, interpret=self._interpret, raw_windows=True
             )
         # Queue the result's copy to the host behind the forward, so the
         # round's in-order harvest finds each block's probabilities there
@@ -686,18 +688,14 @@ class MonitorEngine:
     def _submit_rows(self, items, slots: int) -> jax.Array:
         """DispatchCore submit hook: fill a buffer of the chosen slot shape
         that no in-flight block owns with the live items and dispatch it.
-        With the front-end on the device an item is a ``(ring, depth)`` pair
-        of the round's window plan, and the ring copies that window straight
-        into its row of the block; otherwise an item is a feature row."""
+        An item is a ``(ring, depth)`` pair of the round's window plan, and
+        the ring copies that window straight into its row of the block."""
         tel = self.telemetry
         if tel.on:
             i = tel.open("engine.pack")
-        if self.on_device_features:
-            block = self._pool.buffer(slots, len(items))
-            for j, (ring, depth) in enumerate(items):
-                ring.copy_windows(block[j : j + 1], depth)
-        else:
-            block = self._pool.pack(items, slots)
+        block = self._pool.buffer(slots, len(items))
+        for j, (ring, depth) in enumerate(items):
+            ring.copy_windows(block[j : j + 1], depth)
         if tel.on:
             tel.close(i, len(items))
         return self._submit(block)
@@ -713,9 +711,8 @@ class MonitorEngine:
         return out
 
     def _forward(self, items) -> np.ndarray:
-        """Micro-batch the round's items — ``(n, row_width)`` feature rows,
-        or the ``(ring, depth)`` window plan when the front-end is fused —
-        through the shared dispatch core: the slot policy picks each block's
+        """Micro-batch the round's ``(ring, depth)`` window plan through the
+        shared dispatch core: the slot policy picks each block's
         shape (fixed ``batch_slots``, or the adaptive ladder), each block
         gets a preallocated buffer of its own, and every block of the round
         is submitted before the first harvest-time ``block_until_ready``.
@@ -732,11 +729,10 @@ class MonitorEngine:
             self._qp,
             self.cfg,
             self.slot_policy.ladder,
-            row_width=self._in_width,
             mesh=self._mesh,
             axis_name=self._mesh_axis,
             interpret=self._interpret,
-            raw_windows=self.on_device_features,
+            raw_windows=True,
         )
         return self.slot_policy.ladder
 
@@ -751,11 +747,10 @@ class MonitorEngine:
             self._qp,
             self.cfg,
             self.slot_policy.ladder,
-            row_width=self._in_width,
             mesh=self._mesh,
             axis_name=self._mesh_axis,
             interpret=self._interpret,
-            raw_windows=self.on_device_features,
+            raw_windows=True,
         )
 
     def step(self) -> list[WindowScore]:
@@ -804,20 +799,14 @@ class MonitorEngine:
         n_win = int(alloc.sum())
         if tel.on:
             i = tel.open("engine.gather")
+        # The window plan: each block's rows are copied ring -> block just
+        # before that block is submitted (``_submit_rows``).
         rings = self._rings
-        if self.on_device_features:
-            # The window plan: each block's rows are copied ring -> block
-            # just before that block is submitted (``_submit_rows``).
-            items = [
-                (rings[s], d)
-                for s, k in zip(cand.tolist(), alloc.tolist())
-                for d in range(k)
-            ]
-        else:
-            stacked = np.concatenate(
-                [rings[s].peek_windows(int(k)) for s, k in zip(cand, alloc) if k]
-            )
-            items = features.batch_features(stacked, self.feature_kind)
+        items = [
+            (rings[s], d)
+            for s, k in zip(cand.tolist(), alloc.tolist())
+            for d in range(k)
+        ]
         if tel.on:
             tel.close(i, n_win * self.window * 4)  # float32 bytes the round reads
         p_uav = self._forward(items)[:, 1]  # may raise: nothing committed yet

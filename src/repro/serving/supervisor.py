@@ -13,7 +13,7 @@ bitwise standard, not a tolerance:
   recovery path, so rebuilding a worker is cheap and exact);
 * **execution lanes** — with ``lanes="threads"`` every worker gets a named
   lane thread that runs its engine's ingest→dispatch→harvest beat, so one
-  worker's host feature extraction overlaps another worker's device
+  worker's host block packing overlaps another worker's device
   scoring through the dispatch core's queued blocks.  Ingest enters a
   shared front-of-fleet :class:`~repro.serving.batching.IngestQueue` and
   is routed to workers through the ``_route`` table at the top of each
@@ -290,8 +290,8 @@ class FleetSupervisor:
     lanes:
         ``None`` (default) steps the workers sequentially on the caller's
         thread.  ``"threads"`` gives each worker a named execution lane:
-        all live workers' round beats run concurrently (host feature
-        extraction for one overlaps device scoring for another) and
+        all live workers' round beats run concurrently (host block
+        packing for one overlaps device scoring for another) and
         :meth:`push` becomes a non-blocking enqueue onto a shared ingest
         queue drained at the top of each round.  Per-stream results are
         bitwise identical either way.

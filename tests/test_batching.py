@@ -92,21 +92,29 @@ def test_adaptive_total_padding_bounded_by_ladder():
 # ---------------------------------------------------------------------------
 
 
+def _fill(pool, rows, slots):
+    """A buffer of ``slots`` rows from the pool with ``rows`` written into
+    its live rows, as the engine's ring -> block copy writes them."""
+    block = pool.buffer(slots, len(rows))
+    block[: len(rows)] = rows
+    return block
+
+
 def test_block_pool_rotation_depth():
     """Each block handed out since the last rewind has a buffer of its own;
     after ``rewind`` the pool hands the same buffers out again, in order,
     and grows only when a round holds more blocks than any before it."""
     pool = BlockPool(width=3)
     rows = [np.full(3, i, np.float32) for i in range(10)]
-    b0 = pool.pack(rows[:2], 4)
-    b1 = pool.pack(rows[2:4], 4)
-    b2 = pool.pack(rows[4:6], 4)
+    b0 = _fill(pool, rows[:2], 4)
+    b1 = _fill(pool, rows[2:4], 4)
+    b2 = _fill(pool, rows[4:6], 4)
     assert b0 is not b1 and b1 is not b2 and b0 is not b2
     pool.rewind()
-    assert pool.pack(rows[6:8], 4) is b0
-    assert pool.pack(rows[8:10], 4) is b1
-    assert pool.pack(rows[:1], 4) is b2
-    b3 = pool.pack(rows[1:2], 4)  # a deeper round: one more buffer
+    assert _fill(pool, rows[6:8], 4) is b0
+    assert _fill(pool, rows[8:10], 4) is b1
+    assert _fill(pool, rows[:1], 4) is b2
+    b3 = _fill(pool, rows[1:2], 4)  # a deeper round: one more buffer
     assert all(b3 is not b for b in (b0, b1, b2))
 
 
@@ -130,21 +138,21 @@ def test_block_pool_outstanding_blocks_never_alias(k):
 
 def test_block_pool_zeroes_dead_tail():
     pool = BlockPool(width=2)
-    full = pool.pack([np.ones(2, np.float32)] * 3, 3)
+    full = _fill(pool, [np.ones(2, np.float32)] * 3, 3)
     np.testing.assert_array_equal(full, np.ones((3, 2), np.float32))
     pool.rewind()
-    partial = pool.pack([np.full(2, 7.0, np.float32)], 3)
+    partial = _fill(pool, [np.full(2, 7.0, np.float32)], 3)
     assert partial is full
     np.testing.assert_array_equal(partial[0], np.full(2, 7.0, np.float32))
     np.testing.assert_array_equal(partial[1:], np.zeros((2, 2), np.float32))
 
 
 def test_block_pool_buffer_shares_the_rotation_and_zeroes_the_dead_tail():
-    """``buffer`` hands out the same buffers as ``pack``, for a caller that
-    fills the live rows itself: a reused buffer's dead tail is silence
-    again, its live rows are left to the caller."""
+    """``buffer`` hands a caller that fills the live rows itself the
+    buffers in rotation: a reused buffer's dead tail is silence again, its
+    live rows are left to the caller."""
     pool = BlockPool(width=2)
-    a = pool.pack([np.full(2, 5.0, np.float32)] * 3, 3)
+    a = _fill(pool, [np.full(2, 5.0, np.float32)] * 3, 3)
     b = pool.buffer(3, 3)
     b[:] = 9.0
     assert b is not a
@@ -153,22 +161,22 @@ def test_block_pool_buffer_shares_the_rotation_and_zeroes_the_dead_tail():
     assert again is a  # the next round starts from the first buffer
     np.testing.assert_array_equal(again[0], np.full(2, 5.0, np.float32))
     np.testing.assert_array_equal(again[1:], np.zeros((2, 2), np.float32))
-    assert pool.pack([np.ones(2, np.float32)], 3) is b
+    assert _fill(pool, [np.ones(2, np.float32)], 3) is b
     with pytest.raises(ValueError, match="do not fit"):
         pool.buffer(3, 4)
 
 
 def test_block_pool_shapes_rotate_independently():
     pool = BlockPool(width=1)
-    a = pool.pack([np.zeros(1, np.float32)], 2)
-    b = pool.pack([np.zeros(1, np.float32)], 4)  # other shape: fresh pool
-    c = pool.pack([np.ones(1, np.float32)], 2)
+    a = _fill(pool, [np.zeros(1, np.float32)], 2)
+    b = _fill(pool, [np.zeros(1, np.float32)], 4)  # other shape: fresh pool
+    c = _fill(pool, [np.ones(1, np.float32)], 2)
     assert a.shape == (2, 1) and b.shape == (4, 1)
     assert a is not c  # shape 2 handed out its second buffer
     pool.rewind()
-    assert pool.pack([np.zeros(1, np.float32)], 4) is b  # untouched by shape 2
+    assert _fill(pool, [np.zeros(1, np.float32)], 4) is b  # untouched by shape 2
     with pytest.raises(ValueError, match="do not fit"):
-        pool.pack([np.zeros(1, np.float32)] * 3, 2)
+        pool.buffer(2, 3)
 
 
 # ---------------------------------------------------------------------------

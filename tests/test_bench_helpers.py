@@ -1,12 +1,9 @@
 """Unit tests for the shared benchmark row-builder helpers.
 
-The serving benches derive their percentile columns through
+Round-latency percentile columns come from
 :func:`benchmarks.common.percentile_fields`, which must degrade to null
-fields on zero recorded rounds (SMOKE runs score everything in
-warmup/drain) instead of letting ``np.percentile`` raise on an empty
-list.  ``benchmarks.bench_serving`` itself is deliberately NOT imported
-here — it forces a simulated host-device count before jax import, which
-must not leak into the unit-test process.
+fields on zero recorded rounds (a run that scores everything in warmup or
+drain) instead of letting ``np.percentile`` raise on an empty list.
 """
 import csv
 import io

@@ -77,7 +77,7 @@ def _detector():
         input_len=features.FEATURE_DIMS["zcr"], channels=(4, 8), hidden=8
     )
     params = cnn1d.init_params(jax.random.PRNGKey(0), cfg)
-    qp = quantize_params(params, cfg, mode="int8")
+    qp = quantize_params(params, cfg, mode="int8", feature_kind="zcr")
     return cfg, qp
 
 

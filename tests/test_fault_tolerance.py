@@ -36,7 +36,7 @@ def detector():
     params = cnn1d.init_params(jax.random.PRNGKey(0), cfg)
     # Bake once: every engine/worker in this module serves the same frozen
     # artifact, exactly like a supervisor rebuilding dead workers.
-    qp = quantize_params(params, cfg, mode="int8")
+    qp = quantize_params(params, cfg, mode="int8", feature_kind="zcr")
     return cfg, qp
 
 
@@ -337,7 +337,7 @@ def test_without_sanitize_nan_poisons_only_its_own_stream():
         input_len=features.FEATURE_DIMS["psd"], channels=(4, 8), hidden=8
     )
     params = cnn1d.init_params(jax.random.PRNGKey(3), cfg)
-    qp = quantize_params(params, cfg, mode="int8")
+    qp = quantize_params(params, cfg, mode="int8", feature_kind="psd")
     rng = np.random.default_rng(10)
     clean = _scene_audio(rng, 2, 2)
 

@@ -62,8 +62,7 @@ def _reference(params, x, modes) -> np.ndarray:
 
 
 def _engine(params, **kw) -> MonitorEngine:
-    args = dict(n_streams=4, feature_kind="waveform", on_device_features=True, batch_slots=8,
-                precision="bf16")
+    args = dict(n_streams=4, feature_kind="waveform", batch_slots=8, precision="bf16")
     return MonitorEngine(params, CFG, **{**args, **kw})
 
 
@@ -92,18 +91,11 @@ def test_engine_and_forward_match_the_reference(params):
     assert check.logit_gap(p_ctl, p_ref) > LOGIT_TOL
 
 
-def test_host_normalised_windows_serve_the_same(params):
-    """``on_device_features=False``: the numpy ``waveform`` front-end
-    normalises on the host and the forward skips its own; the float64
-    oracle and the float32 twin differ only in the last bits."""
-    x = _windows(4, 4)
-    probs = []
-    for on_device in (True, False):
-        eng = _engine(params, on_device_features=on_device)
-        for s in range(4):
-            eng.push(s, x[s])
-        probs.append(np.array([w.p_uav for w in eng.step()]))
-    np.testing.assert_allclose(probs[0], probs[1], rtol=1e-5)
+def test_forward_takes_raw_windows_only(params):
+    """The verifier's input normalisation always runs in-graph: a forward
+    asked to skip it is refused."""
+    with pytest.raises(ValueError, match="raw_windows=True"):
+        accelerator.accelerator_forward(params, jnp.asarray(_windows(1, 4)), CFG)
 
 
 def test_window_alone_equals_window_in_a_full_block(params):
@@ -130,7 +122,7 @@ def test_op_scopes_map_every_matmul_conv_and_reduction(params):
     assert set(scopes.values()) == {"frontend", "waveform", "featproj", "posconv", "attn", "ffn",
                                     "head"}
     hlo = accelerator._forward_verifier.lower(
-        eng._qp, jax.ShapeDtypeStruct((8, 12_800), jnp.float32), True).compile().as_text()
+        eng._qp, jax.ShapeDtypeStruct((8, 12_800), jnp.float32)).compile().as_text()
     found = re.findall(r"^\s+(?:ROOT )?%(\S+) = \S+ (?:dot|convolution|reduce)\(", hlo, re.M)
     assert len(found) > 10
     assert [name for name in found if name not in scopes] == []

@@ -290,50 +290,42 @@ MATRIX_SCRIPT = textwrap.dedent(
         checks += 1 + wr.shape[0]
 
     # End-to-end engine leg on the deployed configuration (pruned + mixed):
-    # uneven chunked delivery, unsharded vs 2-way sharded dispatch, host vs
-    # fused front-end, must all reproduce the batched per-stream reference
-    # bitwise (host features vs one host-features batched forward; on-device
-    # features vs one raw-window batched forward).
-    qp_deploy = quantize_params(params, cfg, mode="int8", prune=spec, policy=mixed)
+    # uneven chunked delivery, unsharded vs 2-way sharded dispatch, must
+    # both reproduce one raw-window batched forward per stream bitwise.
     qp_deploy_dev = quantize_params(
         params, cfg, mode="int8", prune=spec, policy=mixed, feature_kind="zcr"
     )
     n_streams, n_win = 2, 2
     audio = rng.standard_normal((n_streams, n_win * features.N_SAMPLES)).astype(np.float32)
     audio *= (10.0 ** rng.uniform(-2, 2, size=(n_streams, 1))).astype(np.float32)
-    ref, ref_dev = [], []
+    ref_dev = []
     for s in range(n_streams):
         wins = audio[s].reshape(n_win, features.N_SAMPLES)
-        feats = features.batch_features(wins, "zcr")
-        ref.append(np.asarray(accelerator_forward(qp_deploy, jnp.asarray(feats), cfg))[:, 1])
         ref_dev.append(np.asarray(accelerator_forward(
             qp_deploy_dev, jnp.asarray(wins), cfg, raw_windows=True
         ))[:, 1])
-    for on_device in (False, True):
-        for shards in (None, 2):
-            engine = MonitorEngine(
-                params, cfg, n_streams=n_streams, feature_kind="zcr",
-                on_device_features=on_device,
-                batch_slots=2, prune=spec, policy=mixed, shards=shards,
-            )
-            cursors = [0] * n_streams
-            scores = {s: [] for s in range(n_streams)}
-            while any(c < audio.shape[1] for c in cursors):
-                for s in range(n_streams):
-                    n = int(rng.uniform(0.4, 1.6) * features.N_SAMPLES)
-                    engine.push(s, audio[s, cursors[s] : cursors[s] + n])
-                    cursors[s] += n
-                for ws in engine.step():
-                    scores[ws.stream].append(ws.p_uav)
-            for ws in engine.drain():
-                scores[ws.stream].append(ws.p_uav)
-            assert engine.dropped_samples == 0
-            want = ref_dev if on_device else ref
+    for shards in (None, 2):
+        engine = MonitorEngine(
+            params, cfg, n_streams=n_streams, feature_kind="zcr",
+            batch_slots=2, prune=spec, policy=mixed, shards=shards,
+        )
+        cursors = [0] * n_streams
+        scores = {s: [] for s in range(n_streams)}
+        while any(c < audio.shape[1] for c in cursors):
             for s in range(n_streams):
-                got = np.asarray(scores[s], np.float64)
-                assert got.shape == (n_win,)
-                np.testing.assert_array_equal(got, want[s].astype(np.float64))
-                checks += 1
+                n = int(rng.uniform(0.4, 1.6) * features.N_SAMPLES)
+                engine.push(s, audio[s, cursors[s] : cursors[s] + n])
+                cursors[s] += n
+            for ws in engine.step():
+                scores[ws.stream].append(ws.p_uav)
+        for ws in engine.drain():
+            scores[ws.stream].append(ws.p_uav)
+        assert engine.dropped_samples == 0
+        for s in range(n_streams):
+            got = np.asarray(scores[s], np.float64)
+            assert got.shape == (n_win,)
+            np.testing.assert_array_equal(got, ref_dev[s].astype(np.float64))
+            checks += 1
     print("RESULT:" + json.dumps({"ok": True, "checks": checks}))
     """
 )
@@ -344,7 +336,7 @@ def test_matrix_streaming_batched_sharded_bitwise_equal():
     every {pruned, unpruned} x {int8, fxp8, mixed} artifact cell — each cell
     run twice, on host-extracted features and with the DSP front-end fused
     into the jitted program (raw windows) — plus the engine's pruned+mixed
-    deployment end to end in both front-end modes."""
+    deployment end to end, unsharded and sharded."""
     proc = subprocess.run(
         [sys.executable, "-c", MATRIX_SCRIPT],
         capture_output=True,
@@ -356,5 +348,5 @@ def test_matrix_streaming_batched_sharded_bitwise_equal():
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][-1]
     out = json.loads(line[len("RESULT:"):])
     # 6 cells x 2 front-ends x (1 sharded + 4 streamed rows)
-    # + 2 front-ends x 2 engine dispatch modes x 2 streams
-    assert out["ok"] and out["checks"] == 68
+    # + 2 engine dispatch modes x 2 streams
+    assert out["ok"] and out["checks"] == 64

@@ -75,7 +75,6 @@ def test_sharded_forward_single_device_in_process():
     np.testing.assert_array_equal(ref, got)
 
     # the engine's shards=1 route goes through the sharded dispatch too
-    # (batch_slots=4 reuses the (4, M) sharded trace from above)
     engine = MonitorEngine(
         params, cfg, n_streams=2, feature_kind="zcr", batch_slots=4, shards=1
     )
@@ -87,10 +86,10 @@ def test_sharded_forward_single_device_in_process():
     assert len(scored) == 4
     for ws in scored:
         s, i = ws.stream, ws.window_idx
-        feats = features.batch_features(
-            audio[s].reshape(2, features.N_SAMPLES), "zcr"
-        )
-        p = np.asarray(accelerator_forward(params, jnp.asarray(feats), cfg))[i, 1]
+        wins = jnp.asarray(audio[s].reshape(2, features.N_SAMPLES))
+        p = np.asarray(
+            accelerator_forward(engine._qp, wins, cfg, raw_windows=True)
+        )[i, 1]
         assert ws.p_uav == np.float64(p)
 
 
@@ -119,9 +118,11 @@ SHARDED_SCRIPT = textwrap.dedent(
     from repro.models import cnn1d
     from repro.serving.accelerator import accelerator_forward, accelerator_forward_sharded
     from repro.serving.engine import MonitorEngine
+    from repro.serving.quantized_params import quantize_params
 
     cfg = cnn1d.CNNConfig(input_len=features.FEATURE_DIMS["zcr"], channels=(4, 8), hidden=8)
     params = cnn1d.init_params(jax.random.PRNGKey(0), cfg)
+    qp = quantize_params(params, cfg, mode="int8", feature_kind="zcr")
     rng = np.random.default_rng(11)
     checks = 0
 
@@ -132,16 +133,16 @@ SHARDED_SCRIPT = textwrap.dedent(
         # loudness mix: each stream at its own gain over 4 orders of magnitude
         audio *= (10.0 ** rng.uniform(-2, 2, size=(n_streams, 1))).astype(np.float32)
 
-        # (a) one batched unsharded forward per stream = the reference
+        # (a) one batched unsharded raw-window forward per stream = the reference
         ref = []
         for s in range(n_streams):
-            feats = features.batch_features(audio[s].reshape(n_win, features.N_SAMPLES), "zcr")
-            ref.append(np.asarray(accelerator_forward(params, jnp.asarray(feats), cfg))[:, 1])
+            wins = jnp.asarray(audio[s].reshape(n_win, features.N_SAMPLES))
+            ref.append(np.asarray(accelerator_forward(qp, wins, cfg, raw_windows=True))[:, 1])
 
         # (b) streaming through the engine, unsharded vs sharded x{2,4}
         for shards in (None, 2, 4):
             engine = MonitorEngine(
-                params, cfg, n_streams=n_streams, feature_kind="zcr",
+                qp, cfg, n_streams=n_streams, feature_kind="zcr",
                 batch_slots=4, shards=shards,
             )
             cursors = [0] * n_streams

@@ -1,7 +1,8 @@
 """Streaming monitor engine: ring buffers, micro-batching, and the central
 parity guarantee — windows streamed one at a time through the engine produce
 bitwise-identical probabilities and identical track events to one batched
-``accelerator_forward`` + scalar tracker over the same windows.
+raw-window ``accelerator_forward`` of the engine's artifact + scalar tracker
+over the same windows.
 
 That guarantee rests on per-sample activation scales (each row quantises
 independently of its co-batch), so this file is also the regression surface
@@ -17,6 +18,7 @@ from repro.models import cnn1d
 from repro.serving.accelerator import accelerator_forward
 from repro.serving.batching import AdmissionPolicy
 from repro.serving.engine import MonitorEngine, StreamRing
+from repro.serving.quantized_params import quantize_params
 from repro.serving.tracker import track_stream
 
 try:
@@ -27,12 +29,22 @@ except ImportError:
 TRACK_KW = dict(ema_alpha=0.7, enter_threshold=0.02, exit_threshold=0.01, min_duration=1)
 
 
-def _small_detector():
+def _small_detector(kind="zcr"):
     cfg = cnn1d.CNNConfig(
-        input_len=features.FEATURE_DIMS["zcr"], channels=(4, 8), hidden=8
+        input_len=features.FEATURE_DIMS[kind], channels=(4, 8), hidden=8
     )
     params = cnn1d.init_params(jax.random.PRNGKey(0), cfg)
     return cfg, params
+
+
+def _raw_forward(engine, windows):
+    """The engine's artifact over raw windows in one batched forward: the
+    reference every engine score must equal bitwise."""
+    return np.asarray(
+        accelerator_forward(
+            engine._qp, jnp.asarray(windows), engine.cfg, raw_windows=True
+        )
+    )[:, 1].astype(np.float64)
 
 
 # ---------------------------------------------------------------------------
@@ -277,16 +289,13 @@ def test_streaming_parity_bitwise_probs_and_events():
 
     total_events = 0
     for s in range(n_streams):
-        feats = features.batch_features(
-            audio[s].reshape(n_win, features.N_SAMPLES), "zcr"
-        )
         # One batched forward over the whole stream at a different batch
         # size: per-sample activation scales make each row's result
         # independent of its co-batch.
-        probs = np.asarray(accelerator_forward(params, jnp.asarray(feats), cfg))[:, 1]
+        probs = _raw_forward(engine, audio[s].reshape(n_win, features.N_SAMPLES))
         got = np.asarray(scores[s], np.float64)
         assert len(got) == n_win
-        np.testing.assert_array_equal(got, probs.astype(np.float64))
+        np.testing.assert_array_equal(got, probs)
         ref_events = track_stream(probs, **TRACK_KW)
         assert events[s] == ref_events
         total_events += len(ref_events)
@@ -323,7 +332,7 @@ def test_engine_on_device_features_streaming_parity():
 
     engine = MonitorEngine(
         params, cfg, n_streams=n_streams, feature_kind="zcr",
-        on_device_features=True, batch_slots=2, **TRACK_KW,
+        batch_slots=2, **TRACK_KW,
     )
     cursors = [0] * n_streams
     scores: dict[int, list[float]] = {s: [] for s in range(n_streams)}
@@ -358,8 +367,7 @@ def test_engine_on_device_equals_manual_two_stage():
     rng = np.random.default_rng(2)
     wins = rng.standard_normal((4, features.N_SAMPLES)).astype(np.float32)
     engine = MonitorEngine(
-        params, cfg, n_streams=4, feature_kind="zcr",
-        on_device_features=True, batch_slots=4,
+        params, cfg, n_streams=4, feature_kind="zcr", batch_slots=4,
     )
     for s in range(4):
         engine.push(s, wins[s])
@@ -382,7 +390,7 @@ def test_on_device_rounds_equal_forward_of_peeked_windows(per_round):
     n_streams = 5
     engine = MonitorEngine(
         params, cfg, n_streams=n_streams, feature_kind="zcr",
-        on_device_features=True, batch_slots=2, capacity_windows=4,
+        batch_slots=2, capacity_windows=4,
         admission=AdmissionPolicy(max_per_stream_per_round=per_round), **TRACK_KW,
     )
     multi_block = dead_slots = deep = 0
@@ -445,7 +453,7 @@ def test_on_device_failed_round_is_untouched_and_retries_identically(fault):
     def build():
         engine = MonitorEngine(
             params, cfg, n_streams=n_streams, feature_kind="zcr",
-            on_device_features=True, batch_slots=2,
+            batch_slots=2,
             admission=AdmissionPolicy(max_per_stream_per_round=2), **TRACK_KW,
         )
         rng = np.random.default_rng(23)
@@ -497,8 +505,7 @@ def test_on_device_round_copies_each_window_once_into_its_block(monkeypatch):
     block that is sent."""
     cfg, params = _small_detector()
     engine = MonitorEngine(
-        params, cfg, n_streams=5, feature_kind="zcr", on_device_features=True,
-        batch_slots=2,
+        params, cfg, n_streams=5, feature_kind="zcr", batch_slots=2,
     )
     rng = np.random.default_rng(8)
     for s in range(5):
@@ -525,21 +532,55 @@ def test_on_device_round_copies_each_window_once_into_its_block(monkeypatch):
     assert [id(c[2]) for c in copies] == [id(sent[j // 2]) for j in range(5)]
 
 
-def test_engine_rejects_artifact_without_feature_kind():
-    """on_device_features needs the front-end baked into the artifact — a
-    plain artifact must be rejected, not silently served on raw samples."""
+@pytest.mark.parametrize("host_front_end", [False, True])
+def test_engine_rejects_artifact_without_feature_kind(host_front_end):
+    """The engine serves raw windows through the artifact's front-end, so
+    it needs that front-end baked in: a plain artifact must be rejected, not
+    silently served on raw samples; and the removed host front-end cannot
+    be asked for."""
     cfg, params = _small_detector()
+    if host_front_end:
+        with pytest.raises(ValueError, match="host front-end was removed"):
+            MonitorEngine(
+                params, cfg, n_streams=1, feature_kind="zcr",
+                on_device_features=False,
+            )
+        return
     qp = cnn1d.export_quantized(params, cfg, mode="int8")
     assert qp.feature_kind is None
     with pytest.raises(ValueError, match="baked for"):
-        MonitorEngine(
-            qp, cfg, n_streams=1, feature_kind="zcr", on_device_features=True
-        )
+        MonitorEngine(qp, cfg, n_streams=1, feature_kind="zcr")
 
 
-@pytest.mark.parametrize("on_device", [False, True])
+@pytest.mark.parametrize("kind", ["mfcc20", "mel128", "psd", "zcr"])
+def test_engine_serves_every_feature_kind(kind):
+    """Every detector feature kind is served by the fused front-end: the
+    engine's scores over uneven chunks equal one raw-window forward of its
+    artifact, bitwise, and the artifact carries the kind it was baked for."""
+    cfg, params = _small_detector(kind)
+    rng = np.random.default_rng(31)
+    n_streams, n_win = 3, 2
+    audio = rng.standard_normal(
+        (n_streams, n_win * features.N_SAMPLES)
+    ).astype(np.float32)
+    engine = MonitorEngine(
+        params, cfg, n_streams=n_streams, feature_kind=kind, batch_slots=2,
+    )
+    assert engine._qp.feature_kind == kind
+    for s in range(n_streams):
+        half = int(0.7 * features.N_SAMPLES)
+        engine.push(s, audio[s, :half])
+        engine.push(s, audio[s, half:])
+    scores: dict[int, list[float]] = {s: [] for s in range(n_streams)}
+    for ws in engine.drain():
+        scores[ws.stream].append(ws.p_uav)
+    for s in range(n_streams):
+        want = _raw_forward(engine, audio[s].reshape(n_win, features.N_SAMPLES))
+        np.testing.assert_array_equal(np.asarray(scores[s], np.float64), want)
+
+
 @pytest.mark.parametrize("per_round", [1, 4])
-def test_engine_block_buffer_reuse_is_invisible(per_round, on_device):
+def test_engine_block_buffer_reuse_is_invisible(per_round):
     """The preallocated dispatch buffers must behave exactly like the old
     fresh-np.zeros-per-chunk blocks: many rounds with varying ready counts
     (full blocks, partial tails after full blocks), each round's blocks all
@@ -553,18 +594,13 @@ def test_engine_block_buffer_reuse_is_invisible(per_round, on_device):
         (n_streams, n_win * features.N_SAMPLES)
     ).astype(np.float32)
     engine = MonitorEngine(
-        params, cfg, n_streams=n_streams, feature_kind="zcr",
-        on_device_features=on_device, batch_slots=2,
+        params, cfg, n_streams=n_streams, feature_kind="zcr", batch_slots=2,
         admission=AdmissionPolicy(max_per_stream_per_round=per_round),
     )
-    served = engine._qp if on_device else params  # the fused front-end's bake
-    ref = {}
-    for s in range(n_streams):
-        wins = audio[s].reshape(n_win, features.N_SAMPLES)
-        x = wins if on_device else features.batch_features(wins, "zcr")
-        ref[s] = np.asarray(
-            accelerator_forward(served, jnp.asarray(x), cfg, raw_windows=on_device)
-        )[:, 1].astype(np.float64)
+    ref = {
+        s: _raw_forward(engine, audio[s].reshape(n_win, features.N_SAMPLES))
+        for s in range(n_streams)
+    }
     # round 1 fills both slots of the last block (5 ready -> 2+2+1: the
     # stale-tail case), later rounds rewrite previously-padded buffers
     scores: dict[int, list[float]] = {s: [] for s in range(n_streams)}
@@ -584,8 +620,7 @@ def test_engine_depth_histogram_counts_rounds_of_full_blocks():
     blocks: 8 streams over 2 slots queue 4 blocks a round."""
     cfg, params = _small_detector()
     engine = MonitorEngine(
-        params, cfg, n_streams=8, feature_kind="zcr", on_device_features=True,
-        batch_slots=2,
+        params, cfg, n_streams=8, feature_kind="zcr", batch_slots=2,
     )
     assert engine.depth_histogram == {}
     rng = np.random.default_rng(2)
@@ -621,7 +656,7 @@ def test_engine_serves_from_quantized_artifact():
     from repro.serving import quantized_params as qpm
 
     cfg, params = _small_detector()
-    qp = cnn1d.export_quantized(params, cfg, mode="int8")
+    qp = quantize_params(params, cfg, mode="int8", feature_kind="zcr")
     engine = MonitorEngine(qp, cfg, n_streams=2, feature_kind="zcr")
     before = qpm.quantize_calls
     rng = np.random.default_rng(1)

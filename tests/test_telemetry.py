@@ -34,12 +34,11 @@ def detector():
     return cfg, cnn1d.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _serve(detector, on=False, annotate=False, rounds=4, fault_round=None,
-           on_device=True):
+def _serve(detector, on=False, annotate=False, rounds=4, fault_round=None):
     """A tiny engine fed seeded uneven chunks; returns it and every score."""
     cfg, params = detector
     eng = MonitorEngine(params, cfg, n_streams=N_STREAMS, feature_kind="zcr",
-                        on_device_features=on_device, batch_slots=SLOTS)
+                        batch_slots=SLOTS)
     eng.telemetry.on, eng.telemetry.annotate = on, annotate
     rng = np.random.default_rng(3)
     out = []
@@ -105,21 +104,6 @@ def test_spans_nest_per_round_with_fixed_counts(detector):
     assert len(by["engine.pack"]) == eng.forward_calls  # one block fill each
     assert by["engine.launch"] == by["engine.wait"] == [SLOTS] * eng.forward_calls
     assert by["engine.put"] == [SLOTS * width * 4] * eng.forward_calls
-
-
-def test_off_device_round_keeps_the_span_counts(detector):
-    """Host features: the round still stacks its windows (engine.gather
-    counts the raw bytes read) and packs feature rows (engine.pack counts
-    the live rows, one span a block); the device gets feature blocks."""
-    eng, out = _serve(detector, on=True, on_device=False)
-    by = collections.defaultdict(list)
-    for name, *_, count in eng.telemetry.spans:
-        by[name].append(count)
-    assert out and sum(by["engine.gather"]) == len(out) * features.N_SAMPLES * 4
-    assert sum(by["engine.pack"]) == len(out)
-    assert len(by["engine.pack"]) == eng.forward_calls
-    cfg, _ = detector
-    assert by["engine.put"] == [SLOTS * cfg.input_len * 4] * eng.forward_calls
 
 
 def test_self_times_sum_to_push_and_step(detector):
@@ -255,6 +239,6 @@ def test_hlo_scopes_resolves_fusions_through_their_computations():
 def test_engine_op_scopes_cover_every_layer(detector):
     cfg, params = detector
     eng = MonitorEngine(params, cfg, n_streams=2, feature_kind="zcr",
-                        on_device_features=True, batch_slots=SLOTS)
+                        batch_slots=SLOTS)
     scopes = eng.op_scopes()
     assert set(scopes.values()) == {"frontend", "conv0", "conv1", "dense0", "dense1", "softmax"}
